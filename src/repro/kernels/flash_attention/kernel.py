@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1.0e38
 
 
@@ -126,7 +124,7 @@ def flash_attention_bh(q, k, v, *, window: int = 0, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),    # l
             pltpu.VMEM((block_q, h), jnp.float32),    # acc
         ],
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -21,8 +21,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1.0e38
 
 
@@ -234,7 +232,7 @@ def paged_prefill_attention_batch(q, k_pool, v_pool, page_table, q_start, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, nq, h), q.dtype),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, jnp.asarray(q_start, jnp.int32).reshape(b),
@@ -277,7 +275,7 @@ def paged_prefill_attention(q, k_pool, v_pool, page_table, q_start, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nq, h), q.dtype),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(page_table, jnp.asarray(q_start, jnp.int32).reshape(1),
@@ -318,7 +316,7 @@ def paged_attention(q, k_pool, v_pool, page_table, lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nq, h), q.dtype),
-        compiler_params=compat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lens, q, k_pool, v_pool)

@@ -68,8 +68,7 @@ def frag_arg_sharding(cfg: ModelConfig, mesh, arg, kind):
 
 
 def _collect(compiled, chips_per_pod=analyze.CHIPS_PER_POD):
-    from repro import compat
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
     colls = analyze.parse_collectives(txt, chips_per_pod)
     return {

@@ -3,7 +3,7 @@ built by functions only (the dry-run sets XLA_FLAGS before first jax init)."""
 
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -11,12 +11,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = (pod, data, model) — 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(devices: int = 8, model: int = 2):
     """Small mesh for CPU integration tests (requires the host-device flag)."""
-    return compat.make_mesh((devices // model, model), ("data", "model"))
+    return jax.make_mesh((devices // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

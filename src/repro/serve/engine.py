@@ -3,7 +3,8 @@
 CPU-runnable end-to-end: priority continuous batching through the request
 scheduler (admission, chunked prefill, preemption with KV swap to slow
 domains — ``repro.scheduler``), paged prefill + decode through
-kernels/paged_attention (reference impl on CPU, Pallas on TPU), BWAP
+kernels/paged_attention (the Pallas kernels on TPU, the jnp reference on
+CPU — chosen from the pool's platform, :func:`attention_impl`), BWAP
 placement of fresh pages, and online DWP tuning fed by measured step
 latencies. examples/serve_paged.py drives it.
 """
@@ -28,6 +29,27 @@ from repro.scheduler.scheduler import Request, RequestScheduler
 # stays importable (tests, examples).
 Sequence_ = Request
 
+# Longest prefill chunk (tokens) the batched Pallas prefill kernel fits in
+# v5e VMEM: it holds the whole [T, nq, h] query block and [nkv, T*g, 1] f32
+# softmax scratch whose lanes pad to 128. tests/test_chip_compile.py proves
+# T = 256 compiles at qwen2-0.5b widths (nq 14, nkv 2, h 64, page_size 16)
+# and that T = 512 is refused, so the scheduler's default budget fits.
+PALLAS_PREFILL_MAX_CHUNK = 256
+
+_ATTENTION_IMPL = {"tpu": "pallas", "cpu": "reference"}
+
+
+def attention_impl(platform: str) -> str:
+    """Paged-attention implementation for the platform that holds the pool:
+    the Pallas kernels on TPU, the jnp reference on CPU (where the engine's
+    tests are bit-exact against it). Any other platform is an error."""
+    try:
+        return _ATTENTION_IMPL[platform]
+    except KeyError:
+        raise ValueError(
+            f"no paged-attention implementation for platform {platform!r} "
+            f"(supported: {sorted(_ATTENTION_IMPL)})") from None
+
 
 class PagedDecoder:
     """Per-layer decode through the page pool (dense/GQA families)."""
@@ -37,6 +59,8 @@ class PagedDecoder:
         self.cfg = cfg
         self.params = params
         self.view = as_view(pool)        # placement + data plane surface
+        (device,) = self.view.k_pool.devices()
+        self.impl = attention_impl(device.platform)
         gp = params["groups"][0]
         self.stacked = not isinstance(gp, list)
 
@@ -74,6 +98,11 @@ class PagedDecoder:
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
         ps = self.view.page_size
         t = max(len(toks) for toks, _, _ in chunks)
+        if self.impl == "pallas" and t > PALLAS_PREFILL_MAX_CHUNK:
+            raise ValueError(
+                f"prefill chunk of {t} tokens exceeds the "
+                f"{PALLAS_PREFILL_MAX_CHUNK} the Pallas prefill kernel fits "
+                "in VMEM; lower the scheduler's prefill_token_budget")
         toks_pad = np.zeros((b, t), np.int32)
         pos_pad = np.zeros((b, t), np.int32)
         starts = np.zeros(b, np.int32)
@@ -128,7 +157,7 @@ class PagedDecoder:
                 v[seq_i, tok_i])
             att = paged_ops.paged_prefill_attention_batch(
                 q, self.view.k_pool[l], self.view.v_pool[l], tbl, qs,
-                impl="reference")
+                impl=self.impl)
             x = x + (att.reshape(b, t, nq * hd)
                      @ p["attn"]["wo"].astype(cdt))
             h = L.apply_norm(cfg, p["norm2"], x)
@@ -174,7 +203,7 @@ class PagedDecoder:
                                          k[:, 0], v[:, 0])
             att = paged_ops.paged_attention(
                 q[:, 0], self.view.k_pool[l], self.view.v_pool[l],
-                tables, lens + 1, impl="reference")
+                tables, lens + 1, impl=self.impl)
             x = x + (att.reshape(b, 1, nq * hd)
                      @ p["attn"]["wo"].astype(cdt))
             h = L.apply_norm(cfg, p["norm2"], x)

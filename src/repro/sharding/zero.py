@@ -97,8 +97,6 @@ def weighted_allgather(x_pages, owner: np.ndarray, mesh, axis: str = "data"):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     owner_dev = jnp.asarray(owner, jnp.int32)
 
     def body(xp):
@@ -106,5 +104,5 @@ def weighted_allgather(x_pages, owner: np.ndarray, mesh, axis: str = "data"):
         mine = (owner_dev == rank)[:, None].astype(xp.dtype)
         return jax.lax.psum(xp * mine, axis)
 
-    return shard_map(body, mesh=mesh, in_specs=P(None, None),
-                     out_specs=P(None, None))(x_pages)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(None, None),
+                         out_specs=P(None, None), check_vma=False)(x_pages)

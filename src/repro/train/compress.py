@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def _blockwise_q8(x, block: int = 256):
     flat = x.reshape(-1)
@@ -56,9 +54,10 @@ def compressed_psum_grads(grads, residuals, mesh, axis: str = "data",
             red = jax.lax.psum(sent, axis)
             return red, new_r
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
-            in_specs=(P(), P()), out_specs=(P(), P()))(g, r)
+            in_specs=(P(), P()), out_specs=(P(), P()),
+            check_vma=False)(g, r)
 
     flat_g, tree = jax.tree_util.tree_flatten(grads)
     flat_r = jax.tree_util.tree_leaves(residuals)

@@ -118,8 +118,9 @@ def test_elastic_restore_across_meshes(tmp_path):
         cm.save(3, params)
 
         def mesh_of(n):
-            from repro.compat import make_mesh
-            return make_mesh((n // 2, 2), ("data", "model"))
+            return jax.make_mesh(
+                (n // 2, 2), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
         for ndev in (8, 4):     # full fleet, then degraded fleet
             mesh = mesh_of(ndev)
@@ -173,9 +174,9 @@ def test_prefetch_loader_yields_deterministic_batches():
 def test_grad_compression_error_feedback():
     """int8 psum with error feedback: single-step error is bounded; the
     residual carries what was rounded away."""
-    from repro.compat import make_mesh
     from repro.train import compress
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(
         size=(64, 64)).astype(np.float32))}
     r = compress.init_residuals(g)

@@ -7,9 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytest.importorskip("hypothesis",
-                    reason="property tests need hypothesis (optional dep)")
 from hypothesis import given, settings
 from hypothesis import strategies as st
 

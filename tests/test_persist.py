@@ -12,10 +12,7 @@ import re
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:                  # optional dep (see stub)
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import registry
 from repro.core import bwmodel

@@ -106,3 +106,80 @@ def test_dwp_migration_changes_allocation(small_lm):
         lat -= 0.02
     assert pool.tuner.dwp > 0
     assert pool.weights[0] > w0[0]
+
+
+def test_attention_impl_follows_platform(small_lm):
+    """CPU pools run the jnp reference (the engine's CPU tests are
+    bit-exact against it), TPU pools the Pallas kernels; any other platform
+    is refused rather than served through a fallback."""
+    from repro.serve.engine import PagedDecoder, attention_impl
+    cfg, params = small_lm
+    pool = BwapPagePool(cfg, [MemoryDomain("hbm_local", 8, 819.0, True)],
+                        page_size=4)
+    assert PagedDecoder(cfg, params, pool).impl == "reference"
+    assert attention_impl("cpu") == "reference"
+    assert attention_impl("tpu") == "pallas"
+    with pytest.raises(ValueError, match="gpu"):
+        attention_impl("gpu")
+
+
+def _chip_smoke():
+    """chip_smoke.py, which lives at the repo root, as a module."""
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    return smoke
+
+
+def test_chip_smoke_run_on_interpreted_kernels(monkeypatch):
+    """chip_smoke.py's serving path at a tiny size, with the engine steered
+    onto the Pallas kernels in interpret mode as a TPU pool would put it:
+    both passes serve every token, and the decode probe's logits agree
+    with the reference path on the same pool state."""
+    import functools
+
+    from repro.kernels.paged_attention import ops as paged_ops
+    from repro.launch import serve as launch
+    from repro.serve import engine as engine_mod
+
+    smoke = _chip_smoke()
+    monkeypatch.setattr(engine_mod, "attention_impl", lambda _: "pallas")
+    for name in ("paged_attention", "paged_prefill_attention_batch"):
+        monkeypatch.setattr(paged_ops, name, functools.partial(
+            getattr(paged_ops, name), interpret=True))
+
+    cfg = launch.load_config("qwen2-0.5b", smoke=True)
+    lengths, new = [5, 13], 3
+    eng, rep = smoke.run(cfg, lengths, new, page_size=4)
+    assert eng.decoder.impl == "pallas"
+    assert smoke.failures(rep, len(lengths), new) == []
+    # float32 smoke config: kernel and reference differ only in f32
+    # summation order
+    assert rep["logits_rel_err"] < 1e-5
+    assert rep["same_precision_rel_err"] < 1e-5
+    assert rep["steady"]["steps"] == rep["warm"]["steps"]
+    assert 0 < rep["steady_decoder_s"] <= sum(rep["steady"]["step_s"])
+    # the probe and the clock leave the decoder's own methods in place
+    assert not {"decode_step", "forward_chunks"} & vars(eng.decoder).keys()
+
+
+@pytest.mark.parametrize("key, err, tokens", [
+    ("logits_rel_err", 3e-2, 8),           # past LOGITS_RTOL
+    ("same_precision_rel_err", 2e-3, 8),   # past SAME_PRECISION_RTOL
+    ("logits_rel_err", 1e-3, 7),           # one token short
+])
+def test_chip_smoke_failures_name_each_broken_check(key, err, tokens):
+    """A logits error past either tolerance, or a request served short,
+    fails the smoke run by name."""
+    smoke = _chip_smoke()
+    passed = {"finished": 2, "tokens": 8}
+    rep = {"warm": passed, "steady": dict(passed, tokens=tokens),
+           "logits_rel_err": 1e-3, "same_precision_rel_err": 0.0}
+    rep[key] = err
+    (msg,) = smoke.failures(rep, requests=2, new=4)
+    assert ("steady pass" if tokens < 8 else key) in msg
+    assert smoke.failures(dict(rep, logits_rel_err=None, steady=passed),
+                          2, 4) == ["no decode batch of 2 rows was probed"]

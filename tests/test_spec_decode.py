@@ -9,11 +9,8 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:      # bare env: property tests skip individually
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import registry
 from repro.core.dwp import DWPConfig
