@@ -10,10 +10,13 @@ The mix is served twice: a warm-up pass that compiles every shape, then a
 timed pass with fresh prompts of the same lengths. Checks, each fatal:
 
 - every request of both passes finishes with all its new tokens;
-- one decode batch of the warm-up pass, rerun through the jnp reference
-  path on the same pool state, gives logits within ``LOGITS_RTOL`` of the
-  reference at "highest" matmul precision and within
-  ``SAME_PRECISION_RTOL`` of the reference at the served path's own;
+- in the first decode batch of every request, and in the first prefill
+  batch with a chunk that starts past position 0 (so its kernel reads
+  pages an earlier chunk wrote), every attention launch agrees within
+  ``ATTENTION_RTOL`` with the jnp reference attention run on the same
+  inputs;
+- that decode batch, rerun through the reference attention path from the
+  pool state it started from, gives logits within ``LOGITS_RTOL``;
 - the compiled decode and prefill attention ops hold the Pallas kernel
   (``tpu_custom_call``).
 
@@ -35,24 +38,25 @@ ARCH = "qwen2-0.5b"
 REQUESTS = 4
 NEW_TOKENS = 16
 PAGE_SIZE = 16
-SEED = 0
-# Relative L2 errors of the Pallas decode logits against the reference
-# path's on the same pool state. Both paths share every bf16 matmul, and
-# both walk the pages with the same online softmax after casting q, K and V
-# to f32. What differs is the precision of the two f32 attention dots.
+# Relative L2 errors allowed against the jnp reference attention path.
 #
-# LOGITS_RTOL bounds the error against the reference at "highest" matmul
-# precision. At default precision a TPU runs an f32 dot as one bf16 pass,
-# which rounds the softmax weights to bf16 (2^-8 relative) in each of the
-# 24 layers: if every such rounding added up independently, sqrt(24) * 2^-8
-# = 1.9e-2. On a v5e the reference at default precision lies 1.65e-2 from
-# the one at "highest", and so do the Pallas logits.
-LOGITS_RTOL = 2e-2
-# SAME_PRECISION_RTOL bounds the error against the reference at the served
-# path's own (default) precision, where only the order of f32 additions
-# differs: it can flip an occasional bf16 rounding of an attention output,
-# and never a whole page, slot or mask.
-SAME_PRECISION_RTOL = 1e-3
+# ATTENTION_RTOL bounds one attention launch against the reference on the
+# same inputs. Both walk the pages with the same online softmax after
+# casting q, K and V to f32; only the order of f32 additions differs, which
+# flips an occasional bf16 rounding of an output (about 4e-6 on a v5e at
+# qwen2-0.5b widths). A wrong page, slot or mask moves whole rows.
+ATTENTION_RTOL = 1e-3
+# LOGITS_RTOL bounds the logits of a whole step against the reference path
+# run from the same pool. Through 24 layers with a bf16 residual stream,
+# one flipped rounding grows into bf16 noise on every activation: about
+# sqrt(24) * 2^-8 = 1.9e-2 if each layer's rounding adds independently. On
+# a v5e, a prefill batch whose launches each agree within 4e-6 ends 1.2e-2
+# apart in its logits. The decode kernel happens to sum in the reference's
+# order there, and its logits agree bit for bit.
+LOGITS_RTOL = 5e-2
+TOLERANCES = {"decode_attention": ATTENTION_RTOL,
+              "prefill_attention": ATTENTION_RTOL,
+              "decode_logits": LOGITS_RTOL}
 
 
 class CompileCounter(contextlib.AbstractContextManager):
@@ -88,82 +92,86 @@ class CompileCounter(contextlib.AbstractContextManager):
         return False
 
 
-def _errors(got, want) -> tuple[float, float]:
-    """Relative L2 and max absolute error of ``got`` against ``want``."""
+def _rel_err(got, want) -> float:
+    """Relative L2 error of ``got`` against ``want``."""
     import numpy as np
     a, b = np.asarray(got, np.float32), np.asarray(want, np.float32)
-    return (float(np.linalg.norm(a - b) / np.linalg.norm(b)),
-            float(np.abs(a - b).max()))
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
-class DecodeProbe:
-    """Reruns the engine's first decode batch of ``batch`` rows through the
-    reference attention path on the same pool state, then puts back the
-    served path's pool so serving continues unchanged.
+class CheckedOps:
+    """The paged-attention ops module as the engine calls it, with every
+    launch also run through the jnp reference on the same inputs; the
+    worst relative error so far is ``error``."""
 
-    The reference runs twice: at "highest" matmul precision and at the
-    served path's own (default) precision."""
+    def __init__(self):
+        self.error = 0.0
+
+    def __getattr__(self, name):
+        from repro.kernels.paged_attention import ops as paged_ops
+        op = getattr(paged_ops, name)
+
+        def launch(*args, impl):
+            out = op(*args, impl=impl)
+            ref = op(*args, impl="reference")
+            self.error = max(self.error, _rel_err(out, ref))
+            return out
+        return launch
+
+
+class ReferenceProbe:
+    """Checks two served decoder calls of the engine against the jnp
+    reference attention: the first decode batch of ``batch`` rows and the
+    first prefill batch with a chunk that starts past position 0 (it reads
+    K/V an earlier chunk wrote). Each of their attention launches is rerun
+    on the same inputs; the decode batch is also rerun whole through the
+    reference path from the pool state it started from, after which the
+    served path's pool goes back, so serving goes on unchanged."""
 
     def __init__(self, eng, batch: int):
         self.dec = eng.decoder
         self.ref = copy.copy(self.dec)
         self.ref.impl = "reference"
         self.batch = batch
-        self.rel_err = None
-        self.max_abs_err = None
-        self.same_precision_rel_err = None
-        self.dec.decode_step = self._decode_step     # shadows the method
+        self.errors: dict[str, float] = {}
+        # shadow the decoder's methods on the instance
+        self.dec.decode_step = self._decode_step
+        self.dec.forward_chunks = self._forward_chunks
 
     def remove(self):
-        del self.dec.decode_step
+        del self.dec.decode_step, self.dec.forward_chunks
 
-    def _reference(self, pools, *args):
-        self.dec.view.k_pool, self.dec.view.v_pool = pools
-        return self.ref.decode_step(*args)
+    def _checked(self, key, method, *args, **kwargs):
+        """The served call with each attention launch checked."""
+        from repro.serve import engine
+        ops, engine.paged_ops = engine.paged_ops, CheckedOps()
+        try:
+            out = getattr(type(self.dec), method)(self.dec, *args, **kwargs)
+            self.errors[key] = engine.paged_ops.error
+        finally:
+            engine.paged_ops = ops
+        return out
 
     def _decode_step(self, tokens, tables, lens, positions):
-        import jax
+        args = (tokens, tables, lens, positions)
+        if "decode_logits" in self.errors or tokens.shape[0] != self.batch:
+            return type(self.dec).decode_step(self.dec, *args)
         view = self.dec.view
         before = view.k_pool, view.v_pool
-        args = (tokens, tables, lens, positions)
-        served = type(self.dec).decode_step(self.dec, *args)
-        if self.rel_err is not None or tokens.shape[0] != self.batch:
-            return served
+        served = self._checked("decode_attention", "decode_step", *args)
         after = view.k_pool, view.v_pool
-        with jax.default_matmul_precision("highest"):
-            ref = self._reference(before, *args)
-        same = self._reference(before, *args)
+        view.k_pool, view.v_pool = before
+        ref = self.ref.decode_step(*args)
         view.k_pool, view.v_pool = after
-        self.rel_err, self.max_abs_err = _errors(served, ref)
-        self.same_precision_rel_err, _ = _errors(served, same)
+        self.errors["decode_logits"] = _rel_err(served, ref)
         return served
 
-
-class DecoderClock:
-    """Host seconds spent inside the decoder's prefill and decode calls,
-    each ended by ``block_until_ready`` on its output and the pool: the
-    model's share of a step. The rest of a step is host work in the
-    scheduler, the fabric and sampling."""
-
-    def __init__(self, eng):
-        self.dec = eng.decoder
-        self.seconds = 0.0
-        for name in ("forward_chunks", "decode_step"):
-            setattr(self.dec, name, self._timed(getattr(type(self.dec), name)))
-
-    def remove(self):
-        del self.dec.forward_chunks, self.dec.decode_step
-
-    def _timed(self, method):
-        def call(*args, **kwargs):
-            import jax
-            t0 = time.perf_counter()
-            out = method(self.dec, *args, **kwargs)
-            jax.block_until_ready((out, self.dec.view.k_pool,
-                                   self.dec.view.v_pool))
-            self.seconds += time.perf_counter() - t0
-            return out
-        return call
+    def _forward_chunks(self, chunks, **kwargs):
+        if "prefill_attention" in self.errors or \
+                all(start == 0 for _, _, start in chunks):
+            return type(self.dec).forward_chunks(self.dec, chunks, **kwargs)
+        return self._checked("prefill_attention", "forward_chunks", chunks,
+                             **kwargs)
 
 
 def kernels_in_hlo(eng) -> dict[str, bool]:
@@ -188,37 +196,32 @@ def kernels_in_hlo(eng) -> dict[str, bool]:
             for name, low in (("decode", decode), ("prefill", prefill))}
 
 
-def run(cfg, lengths, new: int, *, page_size: int = PAGE_SIZE,
-        seed: int = SEED):
-    """Build the engine, serve the mix twice and probe one decode batch.
-    Returns the engine and a report of what to print and check."""
+def run(cfg, lengths, new: int, *, page_size: int = PAGE_SIZE):
+    """Build the engine, serve the mix twice and probe a decode and a
+    prefill call of the first pass. Returns the engine and a report of what
+    to print and check."""
     import jax
     from repro.launch import serve as launch
 
     t0 = time.perf_counter()
-    eng = launch.build_engine(cfg, lengths, new, page_size=page_size,
-                              seed=seed)
+    eng = launch.build_engine(cfg, lengths, new, page_size=page_size)
     jax.block_until_ready(eng.params)
     setup_s = time.perf_counter() - t0
-    probe = DecodeProbe(eng, batch=len(lengths))
+    probe = ReferenceProbe(eng, batch=len(lengths))
     with CompileCounter() as warm_c:
-        warm = launch.serve(eng, launch.make_prompts(cfg, lengths, seed + 1))
+        warm = launch.serve(eng, launch.make_prompts(cfg, lengths,
+                                                     launch.SEED + 1))
     probe.remove()
-    clock = DecoderClock(eng)
     with CompileCounter() as steady_c:
         steady = launch.serve(eng, launch.make_prompts(cfg, lengths,
-                                                       seed + 2))
-    clock.remove()
+                                                       launch.SEED + 2))
     return eng, {
         "setup_s": setup_s, "warm": warm, "steady": steady,
         "warm_compiles": warm_c.compiles,
         "warm_compile_s": warm_c.seconds,
         "warm_cache_hits": warm_c.cache_hits,
         "steady_compiles": steady_c.compiles,
-        "steady_decoder_s": clock.seconds,
-        "logits_rel_err": probe.rel_err,
-        "logits_max_abs_err": probe.max_abs_err,
-        "same_precision_rel_err": probe.same_precision_rel_err,
+        "errors": probe.errors,
     }
 
 
@@ -229,12 +232,13 @@ def failures(report, requests: int, new: int) -> list[str]:
         if p["finished"] != requests or p["tokens"] != requests * new:
             out.append(f"{name} pass served {p['finished']}/{requests} "
                        f"requests, {p['tokens']}/{requests * new} tokens")
-    if report["logits_rel_err"] is None:
-        return out + [f"no decode batch of {requests} rows was probed"]
-    for key, tol in (("logits_rel_err", LOGITS_RTOL),
-                     ("same_precision_rel_err", SAME_PRECISION_RTOL)):
-        if not report[key] <= tol:
-            out.append(f"decode logits {key} {report[key]} > {tol}")
+    errors = report["errors"]
+    if "decode_logits" not in errors:
+        out.append(f"no decode batch of {requests} rows was probed")
+    if "prefill_attention" not in errors:
+        out.append("no prefill chunk past position 0 was probed")
+    out += [f"{key} error {err} against the reference > {TOLERANCES[key]}"
+            for key, err in errors.items() if not err <= TOLERANCES[key]]
     return out
 
 
@@ -273,15 +277,8 @@ def main() -> int:
     print(f"smoke timing, not a benchmark number: median step wall "
           f"{statistics.median(steady['step_s'])} s over "
           f"{steady['steps']} steps")
-    print(f"smoke timing, not a benchmark number: {rep['steady_decoder_s']}"
-          f" s of {sum(steady['step_s'])} s stepping were inside the "
-          f"decoder (prefill and decode, blocked until ready)")
-    print(f"decode logits vs reference at highest precision: rel L2 err "
-          f"{rep['logits_rel_err']}, max abs err "
-          f"{rep['logits_max_abs_err']} (tolerance {LOGITS_RTOL})")
-    print(f"decode logits vs reference at the same precision: rel L2 err "
-          f"{rep['same_precision_rel_err']} "
-          f"(tolerance {SAME_PRECISION_RTOL})")
+    print(f"rel L2 err against the reference path: {rep['errors']} "
+          f"(tolerances {TOLERANCES})")
     print(f"tpu_custom_call in compiled attention ops: {hlo}")
     print(f"peak_bytes_in_use: {stats.get('peak_bytes_in_use')}")
 

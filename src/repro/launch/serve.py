@@ -37,6 +37,7 @@ from repro.serve.engine import ServeEngine
 
 CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
 PROMPT_MIN, PROMPT_MAX = 32, 300
+SEED = 0                  # the weights' seed; prompts are drawn from it too
 
 
 def enable_compile_cache() -> str:
@@ -79,10 +80,10 @@ def pool_pages(lengths, new: int, page_size: int) -> int:
 
 
 def build_engine(cfg: ModelConfig, lengths, new: int, *,
-                 page_size: int = 16, seed: int = 0) -> ServeEngine:
+                 page_size: int = 16) -> ServeEngine:
     """Seeded random weights and a pool sized for the request mix. The pool
     is one domain: on one chip only its HBM is physical memory."""
-    params = jax.jit(LM(cfg).init)(jax.random.PRNGKey(seed))
+    params = jax.jit(LM(cfg).init)(jax.random.PRNGKey(SEED))
     pool = BwapPagePool(cfg, [
         MemoryDomain("hbm_local", pool_pages(lengths, new, page_size),
                      topology.V5E_HBM_BW, True),
@@ -121,15 +122,13 @@ def main():
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--new", type=int, default=16)
     ap.add_argument("--page-size", type=int, default=16)
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     enable_compile_cache()
     cfg = load_config(args.arch, args.smoke)
     lengths = prompt_lengths(args.requests)
-    eng = build_engine(cfg, lengths, args.new, page_size=args.page_size,
-                       seed=args.seed)
-    out = serve(eng, make_prompts(cfg, lengths, args.seed))
+    eng = build_engine(cfg, lengths, args.new, page_size=args.page_size)
+    out = serve(eng, make_prompts(cfg, lengths, SEED))
     dev = jax.devices()[0]
     print(f"{cfg.name} ({cfg.num_layers} layers, d {cfg.d_model}, "
           f"{cfg.compute_dtype}) on {dev.platform} ({dev.device_kind}), "

@@ -220,9 +220,10 @@ class PersistentTier:
         """Move parked pages' bytes out of reserved swap slots into the
         tier. Table references and view holds carry over onto fresh handle
         ids (``remap_physical`` + per-view ``_on_remap``, the same contract
-        every other mover honors); the vacated slots are the caller's (the
-        swap manager returns them to its reservation). Returns
-        ``(handles, seconds)``."""
+        every other mover honors), and every view's ``on_page_remap``
+        subscribers receive the ``{slot: handle}`` map, since the vacated
+        slots are the caller's to reuse (the swap manager returns them to
+        its reservation). Returns ``(handles, seconds)``."""
         fabric = self.fabric
         assert fabric is not None, "tier not attached to a fabric"
         slot_ids = [int(s) for s in slot_ids]
@@ -245,6 +246,9 @@ class PersistentTier:
             handles.append(h)
         fabric.emit("demote", view=view.name, pages=len(handles),
                     handles=list(handles), seconds=seconds)
+        for vv in fabric.views.values():
+            for cb in vv._page_remap_cbs:
+                cb(dict(zip(slot_ids, handles)))
         return handles, seconds
 
     def promote(self, view, handles: Sequence[int]) -> tuple[list[int], float]:

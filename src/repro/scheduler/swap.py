@@ -152,8 +152,10 @@ class KVSwapManager:
         longest-parked (coldest) pages into the persistent tier. Eq.-1
         priced through the tier's bandwidth row; the freed slots rejoin
         the reservation and the forwarding map chases slot -> handle, so
-        a later ``swap_in`` promotes transparently. Returns
-        ``(pages_demoted, seconds)``."""
+        a later ``swap_in`` promotes transparently. Holders subscribed to
+        the view's ``on_page_remap`` get the handles at once; forwarding
+        serves the others only until the slot parks new KV, which retires
+        its chain (``_reuse_slot``). Returns ``(pages_demoted, seconds)``."""
         tier = self.persist
         if tier is None or not self._out or n <= 0:
             return 0, 0.0
@@ -244,6 +246,7 @@ class KVSwapManager:
                 if home is None:
                     continue            # nowhere to vacate: stays borrowed
                 self.slots[self.view.domain_of(home)].remove(home)
+                self._reuse_slot(home)
                 src.append(p)
                 dst.append(home)
             if src:
@@ -350,6 +353,8 @@ class KVSwapManager:
         dst: list[int] = []
         for d, c in zip(self._slot_domains(), counts):
             dst.extend(self.slots[d].pop() for _ in range(int(c)))
+        for p in dst:
+            self._reuse_slot(p)
         src_doms = [self.view.domain_of(p) for p in movable]
         dst_doms = [self.view.domain_of(p) for p in dst]
         self.view.park_pages(movable, dst)
@@ -398,6 +403,11 @@ class KVSwapManager:
             self._demoted.difference_update(demoted)
             seconds += secs
         return [moved.get(p, p) for p in page_ids], seconds
+
+    def _reuse_slot(self, slot: int) -> None:
+        """``slot`` is about to park new KV: a chain left by a demoted
+        occupant must not forward the new one to the tier."""
+        self._moved.pop(slot, None)
 
     def _forward(self, pid: int) -> int:
         """Resolve (and retire) the forwarding chain for one page id."""

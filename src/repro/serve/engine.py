@@ -29,14 +29,25 @@ from repro.scheduler.scheduler import Request, RequestScheduler
 # stays importable (tests, examples).
 Sequence_ = Request
 
-# Longest prefill chunk (tokens) the batched Pallas prefill kernel fits in
-# v5e VMEM: it holds the whole [T, nq, h] query block and [nkv, T*g, 1] f32
-# softmax scratch whose lanes pad to 128. tests/test_chip_compile.py proves
-# T = 256 compiles at qwen2-0.5b widths (nq 14, nkv 2, h 64, page_size 16)
-# and that T = 512 is refused, so the scheduler's default budget fits.
-PALLAS_PREFILL_MAX_CHUNK = 256
+# The batched Pallas prefill kernel holds a chunk's whole query block in
+# v5e VMEM: as a float32 [nkv, T, groups, lanes] tile (groups padded to the
+# 8 sublanes and lanes, the wider of head_dim and page_size, to 128), beside
+# softmax state and scores of the same order. At qwen2-0.5b widths T = 512
+# does not fit, and a 2 MiB tile (T = 256) does. tests/test_chip_compile.py
+# proves that a chunk of pallas_prefill_max_chunk() tokens compiles at every
+# registry width the decoder serves.
+PALLAS_PREFILL_TILE_BYTES = 2 << 20
 
 _ATTENTION_IMPL = {"tpu": "pallas", "cpu": "reference"}
+
+
+def pallas_prefill_max_chunk(cfg: ModelConfig, page_size: int) -> int:
+    """Longest prefill chunk (tokens) whose query tile stays within
+    ``PALLAS_PREFILL_TILE_BYTES``: 256 for qwen2-0.5b at page size 16."""
+    groups = cfg.num_heads // cfg.num_kv_heads
+    lanes = -(-max(cfg.head_dim_, page_size) // 128) * 128
+    token_bytes = 4 * cfg.num_kv_heads * (-(-groups // 8) * 8) * lanes
+    return PALLAS_PREFILL_TILE_BYTES // token_bytes
 
 
 def attention_impl(platform: str) -> str:
@@ -98,11 +109,13 @@ class PagedDecoder:
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
         ps = self.view.page_size
         t = max(len(toks) for toks, _, _ in chunks)
-        if self.impl == "pallas" and t > PALLAS_PREFILL_MAX_CHUNK:
-            raise ValueError(
-                f"prefill chunk of {t} tokens exceeds the "
-                f"{PALLAS_PREFILL_MAX_CHUNK} the Pallas prefill kernel fits "
-                "in VMEM; lower the scheduler's prefill_token_budget")
+        if self.impl == "pallas":
+            limit = pallas_prefill_max_chunk(cfg, ps)
+            if t > limit:
+                raise ValueError(
+                    f"prefill chunk of {t} tokens exceeds the {limit} the "
+                    f"Pallas prefill kernel fits in VMEM at {cfg.name}'s "
+                    "widths; lower the scheduler's prefill_token_budget")
         toks_pad = np.zeros((b, t), np.int32)
         pos_pad = np.zeros((b, t), np.int32)
         starts = np.zeros(b, np.int32)

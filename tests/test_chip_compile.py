@@ -2,9 +2,11 @@
 
 Nothing runs: the TPU compiler, which is installed without a chip, compiles
 each kernel at qwen2-0.5b's published widths (14 query heads, 2 KV heads,
-head dim 64, bf16, page_size 16) for one chip of a described ``v5e:2x2``.
-It refuses what the chip would refuse — a slice not aligned to the tiling,
-more VMEM than a kernel may use — which interpret mode cannot show.
+head dim 64, bf16, page_size 16) for one chip of a described ``v5e:2x2``,
+and the batched prefill kernel at the engine's chunk limit for every other
+registry width the paged decoder serves. The compiler refuses what the chip
+would refuse — a slice not aligned to the tiling, more VMEM than a kernel
+may use — which interpret mode cannot show.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -21,13 +23,18 @@ from repro.kernels.flash_attention.kernel import flash_attention_bh
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.models.lm import LM
 from repro.placement.pool import BwapPagePool, MemoryDomain
-from repro.serve.engine import PALLAS_PREFILL_MAX_CHUNK, PagedDecoder
+from repro.serve.engine import PagedDecoder, pallas_prefill_max_chunk
 
 CFG = registry.get_config("qwen2-0.5b")
 NQ, NKV, HD = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_
 PAGE_SIZE = 16
 POOL_PAGES = 2048
 BF16 = jnp.bfloat16
+# every other registry architecture PagedDecoder serves (dense GQA)
+OTHER_PAGED_ARCHS = [
+    a for a in registry.ARCH_IDS if a != CFG.name
+    and registry.get_config(a).family in ("dense", "vlm")
+    and registry.get_config(a).mla is None]
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +65,15 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _pool(sharding):
-    return _sds((POOL_PAGES, PAGE_SIZE, NKV, HD), BF16, sharding)
+def _pool(sharding, nkv=NKV, hd=HD):
+    return _sds((POOL_PAGES, PAGE_SIZE, nkv, hd), BF16, sharding)
+
+
+def _lower_prefill(sharding, b, t, mp, nq=NQ, nkv=NKV, hd=HD):
+    return paged_ops.paged_prefill_attention_batch.lower(
+        _sds((b, t, nq, hd), BF16, sharding), _pool(sharding, nkv, hd),
+        _pool(sharding, nkv, hd), _sds((b, mp), jnp.int32, sharding),
+        _sds((b,), jnp.int32, sharding), impl="pallas")
 
 
 def _compiled_text(lowered) -> str:
@@ -78,11 +92,23 @@ def test_decode_kernel_compiles(one_chip):
 
 def test_batched_prefill_compiles_at_guard_limit(one_chip):
     """The longest chunk the engine lets through fits the chip's VMEM."""
-    b, t, mp = 2, PALLAS_PREFILL_MAX_CHUNK, 32
-    _compiled_text(paged_ops.paged_prefill_attention_batch.lower(
-        _sds((b, t, NQ, HD), BF16, one_chip), _pool(one_chip),
-        _pool(one_chip), _sds((b, mp), jnp.int32, one_chip),
-        _sds((b,), jnp.int32, one_chip), impl="pallas"))
+    t = pallas_prefill_max_chunk(CFG, PAGE_SIZE)
+    assert t == 256          # the scheduler's default prefill budget
+    _compiled_text(_lower_prefill(one_chip, b=2, t=t, mp=32))
+
+
+@pytest.mark.parametrize("arch", OTHER_PAGED_ARCHS)
+def test_batched_prefill_compiles_at_guard_limit_at_other_widths(one_chip,
+                                                                  arch):
+    """The engine's chunk limit follows the widths: at each other
+    architecture the decoder serves, the longest chunk it lets through
+    fits the chip's VMEM too."""
+    cfg = registry.get_config(arch)
+    t = pallas_prefill_max_chunk(cfg, PAGE_SIZE)
+    assert t >= 16
+    _compiled_text(_lower_prefill(one_chip, b=2, t=t, mp=32,
+                                  nq=cfg.num_heads, nkv=cfg.num_kv_heads,
+                                  hd=cfg.head_dim_))
 
 
 def test_engine_guard_refuses_chunk_past_limit(one_chip):
@@ -91,10 +117,7 @@ def test_engine_guard_refuses_chunk_past_limit(one_chip):
     it writes or launches anything."""
     b, t, mp = 1, 512, 32
     with pytest.raises(Exception, match="(?i)vmem"):
-        paged_ops.paged_prefill_attention_batch.lower(
-            _sds((b, t, NQ, HD), BF16, one_chip), _pool(one_chip),
-            _pool(one_chip), _sds((b, mp), jnp.int32, one_chip),
-            _sds((b,), jnp.int32, one_chip), impl="pallas").compile()
+        _lower_prefill(one_chip, b, t, mp).compile()
 
     # the engine at published widths; weights as shapes — the guard must
     # fire before anything reads them

@@ -444,6 +444,10 @@ def test_property_demote_interleavings(ops):
     oswap = KVSwapManager(opool, reserve_fraction=0.5)
 
     seqs, oseqs, state = [], [], []
+    # a demoted slot parks the next swap-out's KV; holders learn the
+    # handle from the remap, as the scheduler does
+    view.on_page_remap(lambda moves: seqs.__setitem__(
+        slice(None), [[moves.get(p, p) for p in s] for s in seqs]))
     for s in range(3):
         pages, opages = [], []
         for i in range(2):

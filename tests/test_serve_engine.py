@@ -137,8 +137,11 @@ def _chip_smoke():
 def test_chip_smoke_run_on_interpreted_kernels(monkeypatch):
     """chip_smoke.py's serving path at a tiny size, with the engine steered
     onto the Pallas kernels in interpret mode as a TPU pool would put it:
-    both passes serve every token, and the decode probe's logits agree
-    with the reference path on the same pool state."""
+    both passes serve every token, each attention launch of the probed
+    decode and prefill calls agrees with the reference on the same inputs,
+    and so do the decode logits from the same pool state. The longer
+    prompt outruns the 256-token prefill budget, so its second chunk reads
+    pages its first chunk wrote."""
     import functools
 
     from repro.kernels.paged_attention import ops as paged_ops
@@ -152,34 +155,38 @@ def test_chip_smoke_run_on_interpreted_kernels(monkeypatch):
             getattr(paged_ops, name), interpret=True))
 
     cfg = launch.load_config("qwen2-0.5b", smoke=True)
-    lengths, new = [5, 13], 3
-    eng, rep = smoke.run(cfg, lengths, new, page_size=4)
+    lengths, new = [5, 270], 3
+    eng, rep = smoke.run(cfg, lengths, new, page_size=16)
     assert eng.decoder.impl == "pallas"
     assert smoke.failures(rep, len(lengths), new) == []
     # float32 smoke config: kernel and reference differ only in f32
     # summation order
-    assert rep["logits_rel_err"] < 1e-5
-    assert rep["same_precision_rel_err"] < 1e-5
+    assert set(rep["errors"]) == {"decode_attention", "decode_logits",
+                                  "prefill_attention"}
+    assert max(rep["errors"].values()) < 1e-5
     assert rep["steady"]["steps"] == rep["warm"]["steps"]
-    assert 0 < rep["steady_decoder_s"] <= sum(rep["steady"]["step_s"])
-    # the probe and the clock leave the decoder's own methods in place
+    # the probe leaves the decoder's own methods and ops in place
     assert not {"decode_step", "forward_chunks"} & vars(eng.decoder).keys()
+    assert engine_mod.paged_ops is paged_ops
 
 
 @pytest.mark.parametrize("key, err, tokens", [
-    ("logits_rel_err", 3e-2, 8),           # past LOGITS_RTOL
-    ("same_precision_rel_err", 2e-3, 8),   # past SAME_PRECISION_RTOL
-    ("logits_rel_err", 1e-3, 7),           # one token short
+    ("decode_attention", 2e-3, 8),     # past ATTENTION_RTOL
+    ("prefill_attention", 2e-3, 8),
+    ("decode_logits", 6e-2, 8),        # past LOGITS_RTOL
+    ("decode_logits", 1e-4, 7),        # one token short
 ])
 def test_chip_smoke_failures_name_each_broken_check(key, err, tokens):
-    """A logits error past either tolerance, or a request served short,
-    fails the smoke run by name."""
+    """An error against the reference past its tolerance, a request served
+    short, or a probe that never ran fails the smoke run by name."""
     smoke = _chip_smoke()
     passed = {"finished": 2, "tokens": 8}
+    errors = {"decode_attention": 0.0, "decode_logits": 1e-2,
+              "prefill_attention": 1e-4}
     rep = {"warm": passed, "steady": dict(passed, tokens=tokens),
-           "logits_rel_err": 1e-3, "same_precision_rel_err": 0.0}
-    rep[key] = err
+           "errors": dict(errors, **{key: err})}
     (msg,) = smoke.failures(rep, requests=2, new=4)
     assert ("steady pass" if tokens < 8 else key) in msg
-    assert smoke.failures(dict(rep, logits_rel_err=None, steady=passed),
-                          2, 4) == ["no decode batch of 2 rows was probed"]
+    assert smoke.failures(dict(rep, steady=passed, errors={}), 2, 4) == [
+        "no decode batch of 2 rows was probed",
+        "no prefill chunk past position 0 was probed"]
