@@ -1,16 +1,22 @@
-"""Per-request span tracer on the virtual clock (DESIGN.md §10).
+"""Two kinds of span, each on its own clock (DESIGN.md §10).
 
-Records the request lifecycle — admit, queued wait, prefill chunks,
-decode steps, preempt/swap-out/swap-in, tier demote/promote/restore,
-finish — as Chrome/Perfetto trace events. Export with
-:meth:`SpanTracer.export` and load the JSON in ``ui.perfetto.dev`` (or
+:class:`SpanTracer` records the request lifecycle — admit, queued wait,
+prefill chunks, decode steps, preempt/swap-out/swap-in, tier
+demote/promote/restore, finish — as Chrome/Perfetto trace events. Export
+with :meth:`SpanTracer.export` and load the JSON in ``ui.perfetto.dev`` (or
 ``chrome://tracing``): one process row per fabric view (tenant), one
 thread row per request, plus a ``fabric`` thread carrying migration and
-tier activity.
+tier activity. Its timestamps are the scheduler's *virtual* seconds
+(wall + Eq.-1 read time + swap) converted to trace microseconds, so a trace
+from a ``wall_clock=False`` run is byte-stable across machines; no device
+time can be set beside them.
 
-Timestamps are the scheduler's *virtual* seconds converted to trace
-microseconds, so a trace from a ``wall_clock=False`` run is byte-stable
-across machines.
+:func:`span` marks a stretch of the program's own host code as a
+``jax.profiler.TraceAnnotation`` named ``serve.<name>``. It lands in the
+JAX profiler's trace, on the clock the device's operations are timed by,
+so a device's idle gaps can be put down to what the host was doing. It
+records nothing in Python and keeps no buffers: with no profiler running
+a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -18,7 +24,15 @@ from __future__ import annotations
 import json
 import pathlib
 
+import jax
+
+SPAN_PREFIX = "serve."
 _FABRIC_TID = 0          # per-view bus track; request tids are sid + 1
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """Profiler host span ``serve.<name>`` (a context manager)."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
 
 
 class SpanTracer:

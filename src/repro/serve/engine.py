@@ -5,8 +5,12 @@ scheduler (admission, chunked prefill, preemption with KV swap to slow
 domains — ``repro.scheduler``), paged prefill + decode through
 kernels/paged_attention (the Pallas kernels on TPU, the jnp reference on
 CPU — chosen from the pool's platform, :func:`attention_impl`), BWAP
-placement of fresh pages, and online DWP tuning fed by measured step
-latencies. examples/serve_paged.py drives it.
+placement of fresh pages, and online DWP tuning fed by each step's latency
+through ``record_latency``. examples/serve_paged.py drives it.
+
+``ServeEngine.step`` and ``PagedDecoder.decode_step`` mark their phases
+with profiler host spans (``serve.*``, :func:`repro.obs.trace.span`), so a
+device trace can put its idle time down to what the host was doing.
 """
 
 from __future__ import annotations
@@ -22,6 +26,7 @@ from repro.kernels.paged_attention import ops as paged_ops
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.models.lm import LM
+from repro.obs.trace import SPAN_PREFIX, span
 from repro.placement.fabric import as_view
 from repro.scheduler.scheduler import Request, RequestScheduler
 
@@ -184,47 +189,51 @@ class PagedDecoder:
 
     def decode_step(self, tokens, tables, lens, positions):
         """tokens [B,1]; tables [B,MP]; lens [B]; positions [B]."""
-        cfg = self.cfg
-        cdt = jnp.dtype(cfg.compute_dtype)
-        b = tokens.shape[0]
-        nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-        ps = self.view.page_size
-        x = self.params["embed"][tokens].astype(cdt)     # [B,1,d]
-        if cfg.embed_scale:
-            x = x * np.sqrt(cfg.d_model)
-        pos_b = positions[:, None].astype(jnp.int32)
+        with span("decode.dispatch"):
+            cfg = self.cfg
+            cdt = jnp.dtype(cfg.compute_dtype)
+            b = tokens.shape[0]
+            nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+            ps = self.view.page_size
+            x = self.params["embed"][tokens].astype(cdt)     # [B,1,d]
+            if cfg.embed_scale:
+                x = x * np.sqrt(cfg.d_model)
+            pos_b = positions[:, None].astype(jnp.int32)
 
-        for l in range(cfg.num_layers):
-            p = self._layer(l)
-            h = L.apply_norm(cfg, p["norm1"], x)
-            q = (h @ p["attn"]["wq"].astype(cdt)).reshape(b, 1, nq, hd)
-            k = (h @ p["attn"]["wk"].astype(cdt)).reshape(b, 1, nkv, hd)
-            v = (h @ p["attn"]["wv"].astype(cdt)).reshape(b, 1, nkv, hd)
-            if cfg.qkv_bias:
-                q = q + p["attn"]["bq"].astype(cdt).reshape(nq, hd)
-                k = k + p["attn"]["bk"].astype(cdt).reshape(nkv, hd)
-                v = v + p["attn"]["bv"].astype(cdt).reshape(nkv, hd)
-            if cfg.use_rope:
-                q = L.apply_rope(q, pos_b, cfg.rope_theta)
-                k = L.apply_rope(k, pos_b, cfg.rope_theta)
-            # write the batch's K/V into its pages: one scatter per layer
-            # (decode hot path — the per-sequence Python loop cost B whole-
-            # pool copies per layer)
-            pages = jnp.take_along_axis(tables, (positions // ps)[:, None],
-                                        axis=1)[:, 0]
-            self.view.write_decode_batch(l, pages, positions % ps,
-                                         k[:, 0], v[:, 0])
-            att = paged_ops.paged_attention(
-                q[:, 0], self.view.k_pool[l], self.view.v_pool[l],
-                tables, lens + 1, impl=self.impl)
-            x = x + (att.reshape(b, 1, nq * hd)
-                     @ p["attn"]["wo"].astype(cdt))
-            h = L.apply_norm(cfg, p["norm2"], x)
-            x = x + L.mlp_apply(cfg, p["mlp"], h)
-        x = L.apply_norm(cfg, self.params["final_norm"], x)
-        w = (self.params["embed"].T if cfg.tie_embeddings
-             else self.params["head"])
-        return (x @ w.astype(cdt))[:, 0]                 # [B, V]
+            for l in range(cfg.num_layers):
+                with span("decode.layer_params"):
+                    p = self._layer(l)
+                h = L.apply_norm(cfg, p["norm1"], x)
+                q = (h @ p["attn"]["wq"].astype(cdt)).reshape(b, 1, nq, hd)
+                k = (h @ p["attn"]["wk"].astype(cdt)).reshape(b, 1, nkv, hd)
+                v = (h @ p["attn"]["wv"].astype(cdt)).reshape(b, 1, nkv, hd)
+                if cfg.qkv_bias:
+                    q = q + p["attn"]["bq"].astype(cdt).reshape(nq, hd)
+                    k = k + p["attn"]["bk"].astype(cdt).reshape(nkv, hd)
+                    v = v + p["attn"]["bv"].astype(cdt).reshape(nkv, hd)
+                if cfg.use_rope:
+                    q = L.apply_rope(q, pos_b, cfg.rope_theta)
+                    k = L.apply_rope(k, pos_b, cfg.rope_theta)
+                # write the batch's K/V into its pages: one scatter per
+                # layer (decode hot path — the per-sequence Python loop cost
+                # B whole-pool copies per layer)
+                with span("decode.kv_write"):
+                    pages = jnp.take_along_axis(
+                        tables, (positions // ps)[:, None], axis=1)[:, 0]
+                    self.view.write_decode_batch(l, pages, positions % ps,
+                                                 k[:, 0], v[:, 0])
+                with span("decode.attention"):
+                    att = paged_ops.paged_attention(
+                        q[:, 0], self.view.k_pool[l], self.view.v_pool[l],
+                        tables, lens + 1, impl=self.impl)
+                x = x + (att.reshape(b, 1, nq * hd)
+                         @ p["attn"]["wo"].astype(cdt))
+                h = L.apply_norm(cfg, p["norm2"], x)
+                x = x + L.mlp_apply(cfg, p["mlp"], h)
+            x = L.apply_norm(cfg, self.params["final_norm"], x)
+            w = (self.params["embed"].T if cfg.tie_embeddings
+                 else self.params["head"])
+            return (x @ w.astype(cdt))[:, 0]             # [B, V]
 
 
 class ServeEngine:
@@ -291,7 +300,7 @@ class ServeEngine:
         self.prefill_chunks_run = 0        # prefill (the O(n) vs O(n²) gap)
         self.decode_steps = 0              # steps that ran a decode batch
         self.tokens_emitted = 0            # decode tokens committed
-        self.latencies: list[float] = []
+        self.steps = 0                     # calls of step(): step_num
 
     # scheduler views under the pre-scheduler attribute names
     @property
@@ -341,23 +350,25 @@ class ServeEngine:
         chunks = [(s, lo, hi) for s, lo, hi in chunks if hi > lo]
         if not chunks:
             return
-        if not self.incremental_prefill:
+        with span("prefill"):
+            if not self.incremental_prefill:
+                for seq, lo, hi in chunks:
+                    self._prefill_chunk_recompute(seq, lo, hi)
+                return
+            fused = []
             for seq, lo, hi in chunks:
-                self._prefill_chunk_recompute(seq, lo, hi)
-            return
-        fused = []
-        for seq, lo, hi in chunks:
-            # defensive CoW: prefill chunks land in freshly-allocated
-            # exclusive pages, but a fork here is what keeps a mis-planned
-            # write from corrupting another sequence's shared prefix
-            self.view.ensure_writable(seq.pages, lo, hi)
-            self.prefill_chunks_run += 1
-            self.prefill_tokens_computed += hi - lo
-            fused.append((seq.tokens[lo:hi], seq.pages, lo))
-        self.decoder.forward_chunks(fused)
-        for seq, lo, hi in chunks:
-            seq.length = hi
-            self._register_if_done(seq, hi)
+                # defensive CoW: prefill chunks land in freshly-allocated
+                # exclusive pages, but a fork here is what keeps a
+                # mis-planned write from corrupting another sequence's
+                # shared prefix
+                self.view.ensure_writable(seq.pages, lo, hi)
+                self.prefill_chunks_run += 1
+                self.prefill_tokens_computed += hi - lo
+                fused.append((seq.tokens[lo:hi], seq.pages, lo))
+            self.decoder.forward_chunks(fused)
+            for seq, lo, hi in chunks:
+                seq.length = hi
+                self._register_if_done(seq, hi)
 
     def _prefill_chunk_recompute(self, seq: Sequence_, lo: int, hi: int):
         self.view.ensure_writable(seq.pages, lo, hi)
@@ -393,32 +404,45 @@ class ServeEngine:
                                       seq.prefill_target)
 
     def step(self) -> dict:
-        t0 = time.monotonic()
-        plan = self.scheduler.schedule()
-        self._run_prefills(plan.prefill_chunks)
-        batch = plan.batch
-        if not batch and not plan.prefill_chunks:
-            self.scheduler.advance(plan.swap_seconds)
-            return {"active": 0, "pending": len(self.scheduler.pending)}
-        done: list[Sequence_] = []
-        produced_before = {s.sid: s.produced for s in batch}
-        groups = plan.launch_groups
-        if batch:
-            drafts = self._draft(batch)
-            if drafts is not None:
-                # the verify path fuses the whole batch into one
-                # prefill-mode launch; micro-batching applies to plain
-                # greedy decode only
-                groups = None
-                self._verify_step(batch, drafts)
-            else:
-                self._greedy_step(batch, groups)
-            self.decode_steps += 1
-            for s in batch:
-                if s.produced >= s.max_new:
-                    done.append(s)
+        self.steps += 1
+        with jax.profiler.StepTraceAnnotation(SPAN_PREFIX + "step",
+                                              step_num=self.steps):
+            t0 = time.monotonic()
+            with span("schedule"):
+                plan = self.scheduler.schedule()
+            self._run_prefills(plan.prefill_chunks)
+            batch = plan.batch
+            if not batch and not plan.prefill_chunks:
+                self.scheduler.advance(plan.swap_seconds)
+                return {"active": 0, "pending": len(self.scheduler.pending)}
+            done: list[Sequence_] = []
+            produced_before = {s.sid: s.produced for s in batch}
+            groups = plan.launch_groups
+            if batch:
+                drafts = self._draft(batch)
+                if drafts is not None:
+                    # the verify path fuses the whole batch into one
+                    # prefill-mode launch; micro-batching applies to plain
+                    # greedy decode only
+                    groups = None
+                    with span("decode"):
+                        self._verify_step(batch, drafts)
+                else:
+                    self._greedy_step(batch, groups)
+                self.decode_steps += 1
+                for s in batch:
+                    if s.produced >= s.max_new:
+                        done.append(s)
+            wall = time.monotonic() - t0
+            with span("account"):
+                return self._account(plan, batch, groups, done,
+                                     produced_before, wall)
 
-        wall = time.monotonic() - t0
+    def _account(self, plan, batch, groups, done, produced_before,
+                 wall) -> dict:
+        """Everything a step does after its model work: the Eq.-1 bill and
+        the virtual clock, observatory hooks, first-token and finish
+        callbacks, the DWP tuner and migration, re-homing, telemetry."""
         # latency signal = wall clock + analytic BWAP read time + swap
         # transfer time (the CPU has no real memory-domain asymmetry;
         # the Eq.-1 model supplies it); prefill-only steps read no KV, and
@@ -472,7 +496,6 @@ class ServeEngine:
             self.scheduler.finish(s)
         moved = False
         if batch:
-            self.latencies.append(dt)
             # the DWP tuner judges *placement*: feed it the step latency
             # minus swap transfers — a preemption spike says nothing about
             # where the live pages sit and would trigger spurious re-homing
@@ -535,11 +558,12 @@ class ServeEngine:
         # Constant-footprint geometries (SSM state, DESIGN.md §12) never
         # append: their one state page absorbs every step in place.
         if self.view.geometry.grows:
-            for s in batch:
-                if s.length % ps == 0:
-                    self.view.append_page(s.pages)
-                else:
-                    self.view.fork_for_write(s.pages, s.length // ps)
+            with span("pages"):
+                for s in batch:
+                    if s.length % ps == 0:
+                        self.view.append_page(s.pages)
+                    else:
+                        self.view.fork_for_write(s.pages, s.length // ps)
         if groups is not None:
             # compute-follows-data (DESIGN.md §11): one launch per domain
             # group. Each row's attention reads only its own page table and
@@ -553,16 +577,18 @@ class ServeEngine:
     def _decode_launch(self, seqs) -> None:
         """One decode launch over ``seqs`` (the whole batch, or one
         per-domain micro-batch)."""
-        mp = max(len(s.pages) for s in seqs)
-        tables = np.zeros((len(seqs), mp), np.int32)
-        for i, s in enumerate(seqs):
-            tables[i, :len(s.pages)] = s.pages
-        lens = np.asarray([s.length for s in seqs], np.int32)
-        toks = np.asarray([[s.tokens[-1]] for s in seqs], np.int32)
-        logits = self.decoder.decode_step(
-            jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(lens),
-            jnp.asarray(lens))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("decode"):
+            mp = max(len(s.pages) for s in seqs)
+            tables = np.zeros((len(seqs), mp), np.int32)
+            for i, s in enumerate(seqs):
+                tables[i, :len(s.pages)] = s.pages
+            lens = np.asarray([s.length for s in seqs], np.int32)
+            toks = np.asarray([[s.tokens[-1]] for s in seqs], np.int32)
+            logits = self.decoder.decode_step(
+                jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(lens),
+                jnp.asarray(lens))
+            with span("decode.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
         for s, t in zip(seqs, nxt):
             s.tokens.append(int(t))
             s.length += 1          # the decoded token's K/V is now pooled

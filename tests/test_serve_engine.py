@@ -95,6 +95,58 @@ def test_engine_decode_matches_dense_decode(small_lm):
     assert paged_next == dense_next
 
 
+def _three_steps(cfg, params):
+    pool = _pool(cfg, pages=64, page_size=4)
+    eng = ServeEngine(cfg, params, pool, max_batch=2, max_new=8)
+    eng.submit([3, 17, 29, 5, 7])
+    eng.submit([9, 1, 4])
+    for _ in range(3):
+        eng.step()
+    return [list(s.tokens) for s in eng.active + eng.finished]
+
+
+def test_engine_spans_land_in_the_profiler_trace(small_lm, tmp_path):
+    """Each engine step writes its ``serve.*`` host spans into the JAX
+    profiler's trace, nested as the code nests, and tracing changes no
+    token."""
+    cfg, params = small_lm
+    untraced = _three_steps(cfg, params)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(tmp_path, profiler_options=opts)
+    try:
+        traced = _three_steps(cfg, params)
+    finally:
+        jax.profiler.stop_trace()
+    assert traced == untraced
+
+    (xp,) = tmp_path.rglob("*.xplane.pb")
+    planes = jax.profiler.ProfileData.from_file(str(xp)).planes
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+             for p in planes if p.name.startswith("/host:")
+             for line in p.lines for e in line.events
+             if e.name.startswith("serve.")]
+
+    def inside(outer, name):
+        return [x for x in spans if x[2] == name
+                and outer[0] <= x[0] and x[1] <= outer[1]]
+
+    steps = [x for x in spans if x[2] == "serve.step"]
+    assert len(steps) == 3
+    for i, step in enumerate(steps):
+        for name in ("schedule", "pages", "decode", "decode.dispatch",
+                     "decode.sync", "account"):
+            assert len(inside(step, "serve." + name)) == 1, (i, name)
+        # both prompts are prefilled in the first step, in one launch
+        assert len(inside(step, "serve.prefill")) == (1 if i == 0 else 0)
+        (decode,) = inside(step, "serve.decode")
+        assert inside(decode, "serve.decode.dispatch")
+    for dispatch in (x for x in spans if x[2] == "serve.decode.dispatch"):
+        for name in ("layer_params", "kv_write", "attention"):
+            assert len(inside(dispatch, "serve.decode." + name)) == \
+                cfg.num_layers
+
+
 def test_dwp_migration_changes_allocation(small_lm):
     cfg, _ = small_lm
     pool = _pool(cfg, pages=64, page_size=4)
