@@ -1285,8 +1285,11 @@ class FabricView:
     def write_token(self, layer_slot_kv: tuple, page_id: int, slot: int):
         self.pool.write_token(layer_slot_kv, page_id, slot)
 
-    def write_decode_batch(self, layer: int, page_ids, slots, k, v):
-        self.pool.write_decode_batch(layer, page_ids, slots, k, v)
+    def write_rows(self, layer, page_ids, slots, k_rows, v_rows):
+        self.pool.write_rows(layer, page_ids, slots, k_rows, v_rows)
+
+    def pad_rows(self, page_ids, slots, *sources):
+        return self.pool.pad_rows(page_ids, slots, *sources)
 
     # -- reporting --------------------------------------------------------------------
 

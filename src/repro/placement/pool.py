@@ -12,13 +12,17 @@ but one XLA op per batch instead of one copy per page.
 Physically the pool is one array [total_pages, page_size, nkv, hd] per layer;
 domain d owns the contiguous page-id range [offset_d, offset_d + n_d), so the
 paged_attention kernel (kernels/paged_attention) is domain-oblivious and the
-page table *is* the placement. Per-domain counters and stall samples are
-collected in placement.telemetry (DESIGN.md §3.4).
+page table *is* the placement. K/V rows land through ``write_rows`` in
+place: the pool arrays are donated to the write, so code that reads
+``k_pool``/``v_pool`` reads the attribute again after a write. Per-domain
+counters and stall samples are collected in placement.telemetry
+(DESIGN.md §3.4).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import Sequence
 
 import jax
@@ -57,6 +61,42 @@ def default_domains(total_pages: int) -> list[MemoryDomain]:
                      - int(n * 0.20) - int(n * 0.10), topo.V5E_PCIE_BW,
                      False),
     ]
+
+
+# rows one program writes: its dynamic-update-slices are unrolled, so its
+# compile time grows with them (for v5e: about 2 s at 64 rows, 25 s at 512)
+ROW_BLOCK = 64
+
+
+def _write_rows(k_pool, v_pool, layer, page_ids, slots, k_rows, v_rows,
+                start, block):
+    """Rows ``start .. start+block-1`` of ``BwapPagePool.write_rows`` as one
+    program: one dynamic-update-slice per row. On TPU the pool's layout puts
+    the page axis on the lanes; a scatter, or a loop over the rows, runs in
+    another layout and copies the whole pool into it and back, while a
+    dynamic-update-slice writes in the pool's own layout
+    (tests/test_chip_compile.py checks the program). A block that would run
+    past the last row is moved back to end there, as ``dynamic_slice``
+    clamps, and writes its first rows a second time."""
+    page_ids = jax.lax.dynamic_slice_in_dim(page_ids, start, block)
+    slots = jax.lax.dynamic_slice_in_dim(slots, start, block)
+    out = []
+    for pool, rows in ((k_pool, k_rows), (v_pool, v_rows)):
+        if rows.ndim == pool.ndim - 2:           # [N, *row]: one layer
+            rows = rows[None]
+        rows = jax.lax.dynamic_slice_in_dim(rows, start, block, axis=1)
+        size = (rows.shape[0], 1, 1) + pool.shape[3:]
+        for i in range(block):
+            at = (layer, page_ids[i], slots[i]) + (0,) * (pool.ndim - 3)
+            pool = jax.lax.dynamic_update_slice(
+                pool, rows[:, i].reshape(size).astype(pool.dtype), at)
+        out.append(pool)
+    return tuple(out)
+
+
+_write_rows_in_place = jax.jit(_write_rows, donate_argnums=(0, 1),
+                               static_argnames="block")
+_write_rows_copy = jax.jit(_write_rows, static_argnames="block")
 
 
 class BwapPagePool:
@@ -273,14 +313,47 @@ class BwapPagePool:
         """Write one token's K/V across all layers: layer_slot_kv =
         (k [L,nkv,hd], v [L,nkv,hd])."""
         k, v = layer_slot_kv
-        self.k_pool = self.k_pool.at[:, page_id, slot].set(k)
-        self.v_pool = self.v_pool.at[:, page_id, slot].set(v)
+        self.write_rows(0, np.asarray([page_id], np.int32),
+                        np.asarray([slot], np.int32), k[:, None], v[:, None])
 
-    def write_decode_batch(self, layer: int, page_ids, slots, k, v):
-        """Scatter a whole decode batch's K/V for one layer in one op:
-        page_ids/slots [B], k/v [B, nkv, hd]."""
-        self.k_pool = self.k_pool.at[layer, page_ids, slots].set(k)
-        self.v_pool = self.v_pool.at[layer, page_ids, slots].set(v)
+    def write_rows(self, layer, page_ids, slots, k_rows, v_rows):
+        """Write K/V rows into the pool in place: row ``i`` lands at
+        ``(layer, page_ids[i], slots[i])``. Rows ``[N, *row]`` fill one
+        layer; rows ``[Lw, N, *row]`` fill layers ``layer .. layer+Lw-1``.
+        Rows land in order, so a row repeated at the end (:meth:`pad_rows`)
+        writes the same bytes to the same slot again.
+
+        Both pools are donated to a jitted program per ``ROW_BLOCK`` rows,
+        so the write costs the rows it writes and no copy of the pool —
+        unless another holder still has the pool arrays (a caller that kept
+        ``k_pool`` to restore it later): donation would delete its array
+        under it, so that write copies instead. Telemetry counts writes in
+        place against copies."""
+        n = len(page_ids)
+        if not n:
+            return
+        # the attribute and getrefcount's own argument: nobody else holds it
+        sole = (sys.getrefcount(self.k_pool) == 2
+                and sys.getrefcount(self.v_pool) == 2)
+        k_old, v_old = pools = self.k_pool, self.v_pool
+        write = _write_rows_in_place if sole else _write_rows_copy
+        block = min(n, ROW_BLOCK)
+        for start in range(0, n, block):
+            pools = write(*pools, layer, page_ids, slots, k_rows, v_rows,
+                          start, block=block)
+        self.k_pool, self.v_pool = pools
+        self.telemetry.record_kv_write(
+            in_place=k_old.is_deleted() and v_old.is_deleted())
+
+    def pad_rows(self, page_ids, slots, *sources):
+        """Host row indices of a write padded to the next power of two
+        rows, so writes of varying length meet few programs. Padding rows
+        repeat the last row — its page, slot and each ``sources`` index —
+        so they change nothing. ``page_ids`` must not be empty."""
+        n = len(page_ids)
+        pad = (0, (1 << (n - 1).bit_length()) - n)
+        return tuple(np.pad(np.asarray(a, np.int32), pad, mode="edge")
+                     for a in (page_ids, slots) + sources)
 
     # -- DWP tuning / migration -------------------------------------------------
 

@@ -159,6 +159,10 @@ class DomainTelemetry:
         # event-bus subscribers that raised (fabric.emit isolates them so
         # a broken observer never aborts the alloc/free hot path)
         self.subscriber_errors = 0
+        # K/V row writes (BwapPagePool.write_rows): in place, or copied
+        # because the old pool array survived the write
+        self.kv_writes_in_place = 0
+        self.kv_writes_copied = 0
         self.slo: ClassSloCounters | None = None
         self._init_metrics(ring_capacity)
 
@@ -230,6 +234,12 @@ class DomainTelemetry:
             "repro_tier_occupancy_pages",
             "Pages resident per placement tier right now.",
             ("tier", "kind"))
+        kv = m.counter(
+            "repro_kv_writes_total",
+            "K/V row writes into the pool, in place or copying it.",
+            ("mode",))
+        self._m_kv_writes = {True: kv.labels("in_place"),
+                             False: kv.labels("copied")}
         self._m_sub_errors = m.counter(
             "repro_subscriber_errors_total",
             "Fabric event-bus subscribers that raised (isolated).",
@@ -318,6 +328,15 @@ class DomainTelemetry:
         self._m_spec["accepted"].inc(accepted)
         self._m_spec["emitted"].inc(emitted)
 
+    def record_kv_write(self, in_place: bool) -> None:
+        """One ``write_rows`` call: ``in_place`` when the pool arrays it
+        replaced were donated to it."""
+        if in_place:
+            self.kv_writes_in_place += 1
+        else:
+            self.kv_writes_copied += 1
+        self._m_kv_writes[in_place].inc()
+
     def record_subscriber_error(self, event: str) -> None:
         """A fabric event-bus subscriber raised; ``MemoryFabric.emit``
         isolated it so the alloc/free hot path survived."""
@@ -366,6 +385,8 @@ class DomainTelemetry:
             "swap_ins": self.swap_ins,
             "swap_seconds": self.swap_seconds,
             "subscriber_errors": self.subscriber_errors,
+            "kv_writes": {"in_place": self.kv_writes_in_place,
+                          "copied": self.kv_writes_copied},
             "spec": {
                 "steps": self.spec_steps,
                 "drafted": self.spec_drafted,

@@ -95,16 +95,17 @@ class PagedDecoder:
         """Fused multi-sequence chunk forward: ``chunks`` is a list of
         ``(token_ids, pages, start)`` — one sequence's token chunk at
         absolute positions ``[start, start + len(token_ids))`` over its page
-        view. Per layer, every chunk's K/V scatters into its pages first
-        (one op for the whole batch), then all chunks' queries run *one*
-        batched prefill-mode paged-attention launch — prior chunks' (and
-        any trie-shared prefix's) K/V is read from the pool, never
-        recomputed, and same-step chunks of different sequences no longer
-        pay one dispatch each (ROADMAP: batched incremental prefill).
+        view. Per layer, every chunk's K/V is written into its pages first
+        (one in-place write for the whole batch), then all chunks' queries
+        run *one* batched prefill-mode paged-attention launch — prior
+        chunks' (and any trie-shared prefix's) K/V is read from the pool,
+        never recomputed, and same-step chunks of different sequences no
+        longer pay one dispatch each (ROADMAP: batched incremental prefill).
 
         Chunks are right-padded to the longest one; padded queries' K/V
         never lands in the pool and their outputs are discarded, so real
-        positions are bit-identical to running each chunk alone. With
+        positions are bit-identical to running each chunk alone. The K/V
+        write's rows are padded to a power of two (``pad_rows``). With
         ``want_logits`` the padded [B,T,V] logits are returned — the
         speculative verify step (DESIGN.md §7) reads the model's argmax at
         every draft position from them."""
@@ -126,7 +127,7 @@ class PagedDecoder:
         starts = np.zeros(b, np.int32)
         mp = max(-(-(start + len(toks)) // ps) for toks, _, start in chunks)
         tables = np.zeros((b, mp), np.int32)
-        seq_i: list[int] = []      # scatter coordinates of real positions
+        seq_i: list[int] = []      # write coordinates of real positions
         tok_i: list[int] = []
         pids: list[int] = []
         slots: list[int] = []
@@ -141,10 +142,9 @@ class PagedDecoder:
             tok_i.extend(range(ti))
             pids.extend(int(pages[p // ps]) for p in range(start, start + ti))
             slots.extend(p % ps for p in range(start, start + ti))
-        seq_i = np.asarray(seq_i, np.int32)
-        tok_i = np.asarray(tok_i, np.int32)
-        pids = np.asarray(pids, np.int32)
-        slots = np.asarray(slots, np.int32)
+        pids, slots, seq_i, tok_i = (jnp.asarray(a) for a in
+                                     self.view.pad_rows(pids, slots, seq_i,
+                                                        tok_i))
         tbl = jnp.asarray(tables)
         qs = jnp.asarray(starts)
 
@@ -169,10 +169,8 @@ class PagedDecoder:
             # real positions' K/V lands before attention: the causal mask
             # then covers prefix and intra-chunk keys uniformly (padded
             # positions never land)
-            self.view.k_pool = self.view.k_pool.at[l, pids, slots].set(
-                k[seq_i, tok_i])
-            self.view.v_pool = self.view.v_pool.at[l, pids, slots].set(
-                v[seq_i, tok_i])
+            self.view.write_rows(l, pids, slots, k[seq_i, tok_i],
+                                 v[seq_i, tok_i])
             att = paged_ops.paged_prefill_attention_batch(
                 q, self.view.k_pool[l], self.view.v_pool[l], tbl, qs,
                 impl=self.impl)
@@ -214,14 +212,14 @@ class PagedDecoder:
                 if cfg.use_rope:
                     q = L.apply_rope(q, pos_b, cfg.rope_theta)
                     k = L.apply_rope(k, pos_b, cfg.rope_theta)
-                # write the batch's K/V into its pages: one scatter per
-                # layer (decode hot path — the per-sequence Python loop cost
-                # B whole-pool copies per layer)
+                # write the batch's K/V into its pages in place: one
+                # program per layer for the whole batch, at the batch's own
+                # size (every op of this step compiles per batch size)
                 with span("decode.kv_write"):
                     pages = jnp.take_along_axis(
                         tables, (positions // ps)[:, None], axis=1)[:, 0]
-                    self.view.write_decode_batch(l, pages, positions % ps,
-                                                 k[:, 0], v[:, 0])
+                    self.view.write_rows(l, pages, positions % ps,
+                                         k[:, 0], v[:, 0])
                 with span("decode.attention"):
                     att = paged_ops.paged_attention(
                         q[:, 0], self.view.k_pool[l], self.view.v_pool[l],
@@ -339,7 +337,7 @@ class ServeEngine:
           of different sequences fuse into one batched launch
           (``PagedDecoder.forward_chunks``). Long-prompt admission is O(n)
           across chunks, and a step's prefill work is one dispatch.
-        - **recompute**: forward over ``tokens[:hi]``, scatter [lo, hi) —
+        - **recompute**: forward over ``tokens[:hi]``, write [lo, hi) —
           O(hi) per chunk, O(n²) across chunks; kept as the exactness
           oracle (causal attention makes position p's K/V depend only on
           tokens[:p+1], so it equals one-shot prefill bit-for-bit).
@@ -387,11 +385,11 @@ class ServeEngine:
         else:
             k, v = kv["k"][:, 0], kv["v"][:, 0]
         positions = np.arange(lo, hi)
-        pids = np.asarray([seq.pages[p // ps] for p in positions], np.int32)
-        slots = (positions % ps).astype(np.int32)
-        # one scatter per pool array for the whole chunk
-        self.view.k_pool = self.view.k_pool.at[:, pids, slots].set(k[:, lo:hi])
-        self.view.v_pool = self.view.v_pool.at[:, pids, slots].set(v[:, lo:hi])
+        pids, slots, rows = self.view.pad_rows(
+            [seq.pages[p // ps] for p in positions], positions % ps,
+            positions)
+        # one write of the whole chunk, every layer
+        self.view.write_rows(0, pids, slots, k[:, rows], v[:, rows])
         seq.length = hi
         self._register_if_done(seq, hi)
 
@@ -658,7 +656,7 @@ class ServeEngine:
         outputs are token-identical to greedy decoding.
 
         Rejected speculation rolls back *exactly*: snapshotted K/V bytes
-        are scattered back, pages greedy would not yet have allocated
+        are written back, pages greedy would not yet have allocated
         return to the allocator LIFO with the allocation cycle rewound
         (``pool.undo_alloc``), and their references leave the table
         (``table.pop_page``). The unwind runs in **reverse batch order** —
@@ -702,7 +700,7 @@ class ServeEngine:
         logits = self.decoder.forward_chunks(chunks, want_logits=True)
         nxt = np.asarray(jnp.argmax(logits, axis=-1))   # [B,T]
         drafted = accepted = emitted = 0
-        rest_idx: list[int] = []        # snapshot rows to scatter back
+        rest_idx: list[int] = []        # snapshot rows to write back
         rest_pids: list[int] = []
         rest_slots: list[int] = []
         for i, (s, d) in enumerate(zip(batch, drafts)):
@@ -734,10 +732,9 @@ class ServeEngine:
                 assert popped == pid, "speculative page stack out of order"
                 self.view.undo_alloc(pid, m0, m1)
         if rest_idx:
-            idx = np.asarray(rest_idx)
-            self.view.k_pool = self.view.k_pool.at[
-                :, rest_pids, rest_slots].set(snap_k[:, idx])
-            self.view.v_pool = self.view.v_pool.at[
-                :, rest_pids, rest_slots].set(snap_v[:, idx])
+            pids, slots, idx = self.view.pad_rows(rest_pids, rest_slots,
+                                                  rest_idx)
+            self.view.write_rows(0, pids, slots, snap_k[:, idx],
+                                 snap_v[:, idx])
         self.tokens_emitted += emitted
         self.view.telemetry.record_spec(drafted, accepted, emitted)

@@ -6,12 +6,16 @@ head dim 64, bf16, page_size 16) for one chip of a described ``v5e:2x2``,
 and the batched prefill kernel at the engine's chunk limit for every other
 registry width the paged decoder serves. The compiler refuses what the chip
 would refuse — a slice not aligned to the tiling, more VMEM than a kernel
-may use — which interpret mode cannot show.
+may use — which interpret mode cannot show. The pool's K/V row write is
+compiled at the benchmark cell's pool, where the compiled program shows
+whether the pool is updated in place or copied in the chip's layouts.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +26,7 @@ from repro.configs import registry
 from repro.kernels.flash_attention.kernel import flash_attention_bh
 from repro.kernels.paged_attention import ops as paged_ops
 from repro.models.lm import LM
+from repro.placement import pool as pool_mod
 from repro.placement.pool import BwapPagePool, MemoryDomain
 from repro.serve.engine import PagedDecoder, pallas_prefill_max_chunk
 
@@ -137,3 +142,31 @@ def test_flash_kernel_compiles(one_chip):
     x = _sds((bh, s, HD), BF16, one_chip)
     _compiled_text(flash_attention_bh.lower(x, x, x))
 
+
+# the benchmark cell's pool: 26,624 pages of 16 tokens at every layer
+CELL_POOL_PAGES = 26624
+
+
+@pytest.mark.parametrize("rows", [(32, NKV, HD), (512, NKV, HD),
+                                  (CFG.num_layers, 8, NKV, HD)],
+                         ids=["decode_batch", "prefill_step", "all_layers"])
+def test_kv_write_updates_the_pool_in_place(one_chip, rows):
+    """The K/V row write at the cell's pool, for a decode batch, a block of
+    a padded prefill step, and an all-layer write: both pools are aliased to
+    the outputs, and no copy of the pool's shape is left in the program (the
+    pool's layout puts pages on the lanes, where a scatter would copy the
+    whole pool into its own layout and back)."""
+    pool = _sds((CFG.num_layers, CELL_POOL_PAGES, PAGE_SIZE, NKV, HD), BF16,
+                one_chip)
+    n = rows[-3]
+    idx = _sds((n,), jnp.int32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    text = pool_mod._write_rows_in_place.lower(
+        pool, pool, scalar, idx, idx, _sds(rows, BF16, one_chip),
+        _sds(rows, BF16, one_chip), scalar,
+        block=min(n, pool_mod.ROW_BLOCK)).compile().as_text()
+    assert "{0}: (0, {}, may-alias)" in text
+    assert "{1}: (1, {}, may-alias)" in text
+    shape = re.escape(f"bf16[{CFG.num_layers},{CELL_POOL_PAGES},{PAGE_SIZE},"
+                      f"{NKV},{HD}]")
+    assert not re.search(shape + r"\{[^}]*\} copy", text)

@@ -244,6 +244,95 @@ def test_pool_rebalance_raises_when_live_exceeds_capacity(small_cfg):
         pool.rebalance([4, 4, 4])
 
 
+# -- K/V row writes in place ------------------------------------------------
+
+# (layer, page ids, slots, rows span every layer, padded on the host) per
+# write: as the decode step, a prefill over two chunks, a write padded to a
+# power of two rows, the all-layer writes of the recompute prefill and the
+# verify rollback, and writes split into blocks of ROW_BLOCK rows (set to 2
+# there), the last block of five rows moved back over the one before.
+ROW_WRITES = {
+    "decode": [(2, [3, 9, 12], [1, 0, 3], False, False)],
+    "prefill_two_chunks": [(1, [5] * 4, [0, 1, 2, 3], False, True),
+                           (1, [11] * 3, [0, 1, 2], False, True)],
+    "padded": [(0, [1, 4, 4, 8, 15], [3, 0, 1, 2, 3], False, True)],
+    "all_layers": [(0, [2, 2, 7], [0, 1, 3], True, True)],
+    "blocks": [(1, [6, 6, 9, 13, 2], [2, 3, 0, 1, 1], False, False),
+               (0, [2, 2, 7, 7], [0, 1, 0, 1], True, False)],
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_WRITES))
+def test_pool_write_rows_in_place(small_cfg, monkeypatch, case):
+    from repro.placement import pool as pool_mod
+    if case == "blocks":
+        monkeypatch.setattr(pool_mod, "ROW_BLOCK", 2)
+    cfg = dataclasses.replace(small_cfg, num_layers=3)
+    pool = _pool(cfg, pages=16)
+    rng = np.random.default_rng(7)
+    want_k = rng.standard_normal(pool.k_pool.shape).astype(np.float32)
+    want_v = rng.standard_normal(pool.v_pool.shape).astype(np.float32)
+    pool.k_pool, pool.v_pool = jnp.asarray(want_k.copy()), jnp.asarray(
+        want_v.copy())
+    handed = []                 # the pool arrays given to the donated write
+    donated = pool_mod._write_rows_in_place
+
+    def spy(*args, **kw):
+        handed.append(args[:2])
+        return donated(*args, **kw)
+    monkeypatch.setattr(pool_mod, "_write_rows_in_place", spy)
+    programs = 0
+    for layer, pids, slots, every_layer, pad in ROW_WRITES[case]:
+        n = len(pids)
+        lead = (cfg.num_layers, n) if every_layer else (n,)
+        row = pool.k_pool.shape[3:]
+        k, v = (rng.standard_normal(lead + row).astype(np.float32)
+                for _ in range(2))
+        for i, (p, s) in enumerate(zip(pids, slots)):
+            at = (slice(None), p, s) if every_layer else (layer, p, s)
+            want_k[at] = k[..., i, :, :]
+            want_v[at] = v[..., i, :, :]
+        if pad:
+            pids, slots, src = pool.pad_rows(pids, slots, np.arange(n))
+            assert len(pids) == 1 << (n - 1).bit_length()
+            k, v = (np.take(a, src, axis=a.ndim - 3) for a in (k, v))
+        block = min(len(pids), pool_mod.ROW_BLOCK)
+        programs += -(-len(pids) // block)
+        args = (layer, np.asarray(pids, np.int32),
+                np.asarray(slots, np.int32), jnp.asarray(k), jnp.asarray(v))
+        pool.write_rows(*args)
+    # written rows read back; every other row, padding's included, is as it
+    # was
+    np.testing.assert_array_equal(np.asarray(pool.k_pool), want_k)
+    np.testing.assert_array_equal(np.asarray(pool.v_pool), want_v)
+    assert len(handed) == programs
+    assert all(a.is_deleted() for pair in handed for a in pair)
+    assert pool.telemetry.snapshot()["kv_writes"] == {
+        "in_place": len(ROW_WRITES[case]), "copied": 0}
+    text = donated.lower(pool.k_pool, pool.v_pool, *args, 0,
+                         block=block).compile().as_text()
+    assert "{0}: (0, {}, may-alias)" in text
+    assert "{1}: (1, {}, may-alias)" in text
+
+
+def test_pool_write_copies_while_another_holder_keeps_the_pool(small_cfg):
+    """Donating an array someone else still holds would delete it under
+    them: that write copies, and the telemetry says so."""
+    pool = _pool(small_cfg, pages=16)
+    row = jnp.ones((1,) + pool.k_pool.shape[3:], jnp.float32)
+    at = (np.asarray([3], np.int32), np.asarray([1], np.int32))
+    held = pool.k_pool
+    pool.write_rows(0, *at, row, row)
+    assert not held.is_deleted() and not np.asarray(held).any()
+    assert (np.asarray(pool.k_pool[0, 3, 1]) == 1).all()
+    assert pool.telemetry.kv_writes_copied == 1
+    del held
+    pool.write_rows(0, *at, 2 * row, 2 * row)
+    assert (np.asarray(pool.v_pool[0, 3, 1]) == 2).all()
+    assert (pool.telemetry.kv_writes_in_place,
+            pool.telemetry.kv_writes_copied) == (1, 1)
+
+
 # -- two-stage co-scheduled search (paper §III-B3) ---------------------------
 
 def _drive_cotuner(tuner, stall_a_of_dwp, stall_b_of_dwp, max_periods=60):

@@ -95,6 +95,52 @@ def test_engine_decode_matches_dense_decode(small_lm):
     assert paged_next == dense_next
 
 
+def _scatter_write(pool, layer, page_ids, slots, k_rows, v_rows):
+    """The K/V write as it was before it went in place: one eager scatter
+    per pool, each a new pool array."""
+    lay = layer if k_rows.ndim == pool.k_pool.ndim - 2 else \
+        slice(layer, layer + k_rows.shape[0])
+    pool.k_pool = pool.k_pool.at[lay, page_ids, slots].set(k_rows)
+    pool.v_pool = pool.v_pool.at[lay, page_ids, slots].set(v_rows)
+
+
+def test_engine_writes_in_place_with_the_scatter_path_tokens(small_lm,
+                                                              monkeypatch):
+    """40 greedy steps of a 4-sequence batch serve the same tokens through
+    the in-place write as through the eager scatter, and every write of
+    the run is counted in place."""
+    cfg, params = small_lm
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
+               for n in (5, 7, 9, 12)]
+
+    def serve():
+        pool = _pool(cfg, pages=128, page_size=4)
+        eng = ServeEngine(cfg, params, pool, max_batch=4, max_new=48,
+                          wall_clock=False)
+        for p in prompts:
+            eng.submit(list(p))
+        for _ in range(40):
+            eng.step()
+        return pool, [list(s.tokens) for s in eng.active + eng.finished]
+
+    calls = []
+    write = BwapPagePool.write_rows
+    with monkeypatch.context() as m:
+        def counted(self, *args):
+            calls.append(1)
+            write(self, *args)
+        m.setattr(BwapPagePool, "write_rows", counted)
+        pool, served = serve()
+    with monkeypatch.context() as m:
+        m.setattr(BwapPagePool, "write_rows", _scatter_write)
+        _, scattered = serve()
+    assert served == scattered
+    assert len(calls) >= 40 * cfg.num_layers
+    assert pool.telemetry.snapshot()["kv_writes"] == {
+        "in_place": len(calls), "copied": 0}
+
+
 def _three_steps(cfg, params):
     pool = _pool(cfg, pages=64, page_size=4)
     eng = ServeEngine(cfg, params, pool, max_batch=2, max_new=8)
