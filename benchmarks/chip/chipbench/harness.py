@@ -7,7 +7,16 @@ sits in a file of its own that is found by name:
 
 - ``configs/<config>.json``: the model's sizes as run, its source, what was
   cut and assumed, and the serving settings (page size, batch, prefill
-  budget, KV pool pages); ``references/<reference>.py`` beside it;
+  budget, KV pool pages);
+- its architecture, the pair named by the configuration's ``reference``:
+  ``references/<reference>.py``, the plain reference that decides
+  ``correct``, and ``layouts/<reference>.py``, all the harness knows of the
+  architecture: the weight tree (``shapes``, ``INIT``), its hand-over to
+  the program (``program_config``, ``program_params``), the decode
+  kernel's warm-up call (``warm_decode_call``), one layer's attention
+  launch as ``chipbench.roofline.Work`` (``decode_attention``,
+  ``prefill_attention``) and the model operations of a token or a prompt
+  chunk (``token_flops``, ``chunk_flops``);
 - ``traffic/<mix>.json``: parameters of the one generator
   (``chipbench.traffic``);
 - ``metrics/<metric>.py``: a reader ``read(rec) -> float | None`` of the run
@@ -77,27 +86,12 @@ def metrics_of(bench: dict, cell: str, trace: bool) -> list[dict]:
 
 # -- the program under test --------------------------------------------------
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], qkv_bias=bool(cfg.get("qkv_bias")),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=float(cfg["rope_theta"]), rms_eps=cfg["rms_norm_eps"],
-        param_dtype=cfg["torch_dtype"], compute_dtype=cfg["torch_dtype"])
-
-
-def build_engine(cfg: dict, params):
+def build_engine(layout, cfg: dict, params):
     """``ServeEngine`` over one HBM domain of ``kv_pool_pages`` pages."""
     from repro.core import topology
     from repro.placement.pool import BwapPagePool, MemoryDomain
     from repro.serve.engine import ServeEngine
-    mcfg = model_config(cfg)
+    mcfg = layout.program_config(cfg)
     pool = BwapPagePool(mcfg, [MemoryDomain(
         "hbm_local", cfg["kv_pool_pages"], topology.V5E_HBM_BW, True)],
         page_size=cfg["page_size"])
@@ -169,8 +163,8 @@ class Driver:
     """Submits requests and steps the engine, recording when each token of
     each request was produced (the end of the step that produced it)."""
 
-    def __init__(self, eng, rec: Record, cfg: dict):
-        self.eng, self.rec, self.cfg = eng, rec, cfg
+    def __init__(self, eng, rec: Record, layout, cfg: dict):
+        self.eng, self.rec, self.cfg, self.layout = eng, rec, cfg, layout
         self.live: list[Live] = []
         self.in_window = False
         self.clients: dict[int, int] = {}     # closed loop: sid -> client
@@ -218,22 +212,21 @@ class Driver:
 
     def _count(self, secs: float) -> None:
         """Work of the step just run, from its plan."""
-        rec, cfg, plan = self.rec, self.cfg, self._plan
+        rec, cfg, plan, lay = self.rec, self.cfg, self._plan, self.layout
         rec.steps += 1
         rec.step_s += secs
         rec.step_times.append(secs)
         ps = cfg["page_size"]
         if plan.batch:
             ctx = [r.length + 1 for r in plan.batch]
-            rec.decode_launches.append(roofline.decode_attention(cfg, ps, ctx))
-            rec.model_flops += sum(roofline.token_flops(cfg, c) for c in ctx)
+            rec.decode_launches.append(lay.decode_attention(cfg, ps, ctx))
+            rec.model_flops += sum(lay.token_flops(cfg, c) for c in ctx)
         chunks = [(lo, hi - lo) for _, lo, hi in plan.prefill_chunks
                   if hi > lo]
         if chunks:
-            rec.prefill_launches.append(
-                roofline.prefill_attention(cfg, ps, chunks))
+            rec.prefill_launches.append(lay.prefill_attention(cfg, ps, chunks))
             rec.prefill_tokens += sum(n for _, n in chunks)
-            rec.model_flops += sum(roofline.chunk_flops(cfg, s, n)
+            rec.model_flops += sum(lay.chunk_flops(cfg, s, n)
                                    for s, n in chunks)
 
 
@@ -276,12 +269,13 @@ def fill(drv: Driver, trace: traffic.Trace, log=print) -> list:
     return queues
 
 
-def warm_decode(eng, cfg: dict, seconds: float, step_s: float):
+def warm_decode(eng, layout, cfg: dict, seconds: float, step_s: float):
     """Compile (or load) the decode programs that depend on the page-table
     width for the widths the window can reach: a decode batch of the
     running requests grows one page every ``page_size`` steps, and the
-    window holds fewer than ``2 * seconds / step_s`` steps."""
-    from repro.kernels.paged_attention import ops as paged_ops
+    window holds fewer than ``2 * seconds / step_s`` steps. Per width: the
+    table lookup of the page each row writes, and the layout's decode
+    kernel call."""
     running = list(eng.scheduler.running)
     if not running:
         return 0
@@ -290,16 +284,12 @@ def warm_decode(eng, cfg: dict, seconds: float, step_s: float):
     lo = max(len(r.pages) for r in running)
     hi = max(-(-(r.length + steps + 1) // ps) for r in running)
     widths = range(lo, min(hi, lo + 64) + 1)
-    cdt = jnp.dtype(cfg["torch_dtype"])
-    q = jnp.zeros((b, cfg["num_attention_heads"], cfg["head_dim"]), cdt)
-    kp, vp = eng.view.k_pool[0], eng.view.v_pool[0]
     lens = jnp.asarray(np.zeros(b, np.int32))
     for mp in widths:
         tables = jnp.asarray(np.zeros((b, mp), np.int32))
         pages = jnp.take_along_axis(tables, (lens // ps)[:, None], axis=1)
-        out = paged_ops.paged_attention(q, kp, vp, tables, lens + 1,
-                                        impl=eng.decoder.impl)
-        jax.block_until_ready((pages, out))
+        jax.block_until_ready((pages,
+                               layout.warm_decode_call(eng, cfg, b, mp)))
     return len(widths)
 
 
@@ -375,7 +365,7 @@ def sample(live: list[Live], seed: int) -> list[Live]:
     return out
 
 
-def check(cfg: dict, ref, seqs, seed: int, limits: dict,
+def check(layout, cfg: dict, ref, seqs, seed: int, limits: dict,
           control: bool = False) -> dict:
     """The widest gap by which a served token's reference logit lies below
     the reference's best, over the served tokens of ``seqs`` (token list,
@@ -383,7 +373,7 @@ def check(cfg: dict, ref, seqs, seed: int, limits: dict,
     stands in the program's place: the number compared is the widest gap
     of the tokens it puts first at the same positions, and the program's
     own widest gap is kept under ``_program``."""
-    w = weights.make(cfg, seed)
+    w = weights.make(layout, cfg, seed)
     gaps = [ref.logit_gaps(cfg, w, toks, first, with_control=control)
             for toks, first in seqs]
     widest = lambda key: float(max((g[key].max() for g in gaps),
@@ -415,21 +405,23 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float, trace: bool,
     mix = load_json(bench_dir, "traffic", cell["traffic"])
     limits = load_json(bench_dir, "limits", cell["name"])
     ref = load_module(bench_dir, "references", cfg["reference"])
+    layout = load_module(bench_dir, "layouts", cfg["reference"])
     readers = {m["name"]: load_module(bench_dir, "metrics", m["name"])
                for m in metrics_of(bench, cell["name"], trace)}
 
     rec = Record(cfg, peaks=peaks)
     counter = CompileCounter()
-    w = weights.make(cfg, seed)
-    eng = build_engine(cfg, weights.program_params(w))
+    w = weights.make(layout, cfg, seed)
+    eng = build_engine(layout, cfg, layout.program_params(w))
     del w
     tr = traffic.make_trace(mix, seed, cfg["vocab_size"], seconds)
-    drv = Driver(eng, rec, cfg)
+    drv = Driver(eng, rec, layout, cfg)
     queues = fill(drv, tr, log)
     if tr.loop == "closed":
         t = time.perf_counter()
         drv.step()
-        warmed = warm_decode(eng, cfg, seconds, time.perf_counter() - t)
+        warmed = warm_decode(eng, layout, cfg, seconds,
+                             time.perf_counter() - t)
         log(f"warmed decode programs for {warmed} page-table widths")
     jax.block_until_ready((eng.view.k_pool, eng.view.v_pool))
     log(f"set-up: {counter.compiles} programs compiled or loaded, "
@@ -480,7 +472,7 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float, trace: bool,
     # free the program's state before the reference runs
     del eng, drv, picked
     gc.collect()
-    checks = check(cfg, ref, seqs, seed, limits, control)
+    checks = check(layout, cfg, ref, seqs, seed, limits, control)
     ok = checks.pop("_ok")
     program_gap = checks.pop("_program", None)
 

@@ -1,18 +1,24 @@
-"""Seeded random weights in the benchmark's own layout, and their hand-over
-to the program under test.
+"""Seeded random weights in the benchmark's own layout.
 
 The benchmark makes the weights, not the program, so that the reference
 (``references/``) takes nothing the program has made. They are made on
 the device in one jitted call from the seed, in the dtype they are served
-in (``torch_dtype`` of the configuration file), with each layer's matrices
-stacked on a leading layer axis:
+in (``torch_dtype`` of the configuration file). The tree is the
+architecture's: ``shapes(cfg)`` of its layout (``layouts/``), which also
+hands the weights over to the program (``program_params``).
 
-- ``embed`` [vocab, d] and, untied, ``head`` [d, vocab];
-- ``final_norm`` [d]; per layer ``attn_norm`` and ``mlp_norm`` [d], all
-  ones, the published RMSNorm's multiplier as a fresh checkpoint holds it;
-- ``wq`` [d, heads * hd], ``wk``/``wv`` [d, kv_heads * hd], ``wo``
-  [heads * hd, d], and with ``qkv_bias`` the biases ``bq``, ``bk``, ``bv``;
-- ``w_gate``, ``w_up`` [d, ff] and ``w_down`` [ff, d].
+Each leaf is initialised by the rule its name selects (the name as
+``jax.tree_util.keystr`` gives it, leaves in the tree's flattened order,
+each from its own fold of the seed's key):
+
+- a name holding ``norm``: ``ones``;
+- ``['embed']``: ``embed``, rows of std ``EMBED_SCALE``;
+- a last key starting with ``b``: ``bias``, std ``BIAS_SCALE``;
+- any other, a matrix [.., in, out]: ``fan_in``, unit-variance outputs.
+
+A layout's ``INIT`` maps the name of a leaf these rules do not fit to one
+of ``RULES``, such as ``zeros`` for a router's score-correction bias that
+``fan_in`` would scale by its layer count.
 """
 
 from __future__ import annotations
@@ -23,6 +29,15 @@ import jax.numpy as jnp
 EMBED_SCALE = 0.02       # std of embedding rows (the usual initializer range)
 BIAS_SCALE = 0.1         # std of the QKV biases
 
+RULES = {
+    "ones": lambda k, shape: jnp.ones(shape),
+    "zeros": lambda k, shape: jnp.zeros(shape),
+    "embed": lambda k, shape: jax.random.normal(k, shape) * EMBED_SCALE,
+    "bias": lambda k, shape: jax.random.normal(k, shape) * BIAS_SCALE,
+    "fan_in": lambda k, shape: (jax.random.normal(k, shape)
+                                * shape[-2] ** -0.5),
+}
+
 
 def seed_key(seed: int):
     """A PRNG key from a seed of up to 64 bits (``PRNGKey`` keeps only the
@@ -31,70 +46,37 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(cfg: dict) -> dict:
-    d, ff, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    nl = cfg["num_hidden_layers"]
-    q = cfg["num_attention_heads"] * cfg["head_dim"]
-    kv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    layers = {"attn_norm": (nl, d), "mlp_norm": (nl, d),
-              "wq": (nl, d, q), "wk": (nl, d, kv), "wv": (nl, d, kv),
-              "wo": (nl, q, d), "w_gate": (nl, d, ff), "w_up": (nl, d, ff),
-              "w_down": (nl, ff, d)}
-    if cfg.get("qkv_bias"):
-        layers.update(bq=(nl, q), bk=(nl, kv), bv=(nl, kv))
-    out = {"embed": (v, d), "final_norm": (d,), "layers": layers}
-    if not cfg["tie_word_embeddings"]:
-        out["head"] = (d, v)
-    return out
+def _rule(name: str) -> str:
+    """The rule a leaf's name selects."""
+    if "norm" in name:
+        return "ones"
+    if name.endswith("['embed']"):
+        return "embed"
+    if "['b" in name:
+        return "bias"
+    return "fan_in"
 
 
 def _is_shape(x) -> bool:
     return isinstance(x, tuple)
 
 
-def _init(cfg_items, key):
-    cfg = dict(cfg_items)
-    dt = jnp.dtype(cfg["torch_dtype"])
-    leaves, tree = jax.tree.flatten_with_path(shapes(cfg), is_leaf=_is_shape)
-    out = []
-    for i, (path, shape) in enumerate(leaves):
-        name = jax.tree_util.keystr(path)
-        k = jax.random.fold_in(key, i)
-        if "norm" in name:
-            out.append(jnp.ones(shape, dt))
-        elif name.endswith("['embed']"):
-            out.append((jax.random.normal(k, shape) * EMBED_SCALE).astype(dt))
-        elif "['b" in name:
-            out.append((jax.random.normal(k, shape) * BIAS_SCALE).astype(dt))
-        else:            # [.., in, out]: unit-variance outputs
-            out.append((jax.random.normal(k, shape)
-                        * shape[-2] ** -0.5).astype(dt))
-    return jax.tree.unflatten(tree, out)
+def _init(leaves, dtype, key):
+    """``leaves``: (shape, rule) of each leaf, in order."""
+    return [RULES[r](jax.random.fold_in(key, i), shape).astype(dtype)
+            for i, (shape, r) in enumerate(leaves)]
 
 
-_init_jit = jax.jit(_init, static_argnums=0)
+_init_jit = jax.jit(_init, static_argnums=(0, 1))
 
 
-def make(cfg: dict, seed: int) -> dict:
-    """The configuration's weights for ``seed``, on the default device."""
-    return _init_jit(tuple(sorted((k, v) for k, v in cfg.items()
-                                  if isinstance(v, (int, float, str, bool)))),
-                     seed_key(seed))
-
-
-def program_params(w: dict) -> dict:
-    """The same arrays in the layout ``repro`` models take (``LM.init``'s:
-    one stacked layer group; its RMSNorm scales by ``1 + gamma``)."""
-    lw = w["layers"]
-    gamma = lambda x: {"gamma": x - 1}
-    attn = {k: lw[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
-            if k in lw}
-    out = {"embed": w["embed"], "final_norm": gamma(w["final_norm"]),
-           "groups": [{"norm1": gamma(lw["attn_norm"]), "attn": attn,
-                       "norm2": gamma(lw["mlp_norm"]),
-                       "mlp": {"w_gate": lw["w_gate"], "w_up": lw["w_up"],
-                               "w_down": lw["w_down"]}}]}
-    if "head" in w:
-        out["head"] = w["head"]
-    return out
-
+def make(layout, cfg: dict, seed: int) -> dict:
+    """The configuration's weights for ``seed``, in ``layout``'s tree, on
+    the default device."""
+    leaves, tree = jax.tree.flatten_with_path(layout.shapes(cfg),
+                                              is_leaf=_is_shape)
+    names = [jax.tree_util.keystr(path) for path, _ in leaves]
+    spec = tuple((shape, layout.INIT.get(n) or _rule(n))
+                 for n, (_, shape) in zip(names, leaves))
+    return jax.tree.unflatten(
+        tree, _init_jit(spec, cfg["torch_dtype"], seed_key(seed)))

@@ -1,6 +1,7 @@
 """What the benchmark's CPU tests share: a bench directory in a temporary
-place with the committed metric readers and references and configurations
-cut to the registry's smoke sizes, and a driver for ``harness.run``.
+place with the committed metric readers, references and layouts and
+configurations cut to the registry's smoke sizes, and a driver for
+``harness.run``.
 
 Only the tests import this module; they run with ``JAX_PLATFORMS=cpu``,
 where the engine serves through the jnp reference attention.
@@ -50,11 +51,11 @@ FAKE_PEAKS = roofline.Peaks(1e12, 1e11, 1e10, "a stand-in for the CPU")
 
 
 def bench_dir(tmp: pathlib.Path) -> pathlib.Path:
-    """A bench directory under ``tmp``: the committed readers and
-    references, the smoke configurations and mixes, and a limits file for
+    """A bench directory under ``tmp``: the committed readers, references
+    and layouts, the smoke configurations and mixes, and a limits file for
     each of their cells."""
     root = tmp / "bench"
-    for kind in ("metrics", "references"):
+    for kind in ("metrics", "references", "layouts"):
         shutil.copytree(BENCH_DIR / kind, root / kind,
                         ignore=shutil.ignore_patterns("__pycache__"))
     for kind in ("configs", "traffic", "limits"):

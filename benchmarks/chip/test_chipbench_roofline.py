@@ -1,11 +1,16 @@
-"""Operations, bytes and peaks against sums worked out by hand."""
+"""Operations, bytes and peaks against sums worked out by hand: the peaks
+table and the least time of a launch (``chipbench.roofline``), and the
+dense-GQA layout's counts of a served step's work
+(``layouts/dense_gqa.py``)."""
 
 import json
 
 import pytest
 
-from chipbench import roofline
+from chipbench import harness, roofline
 from chipbench_testkit import BENCH_DIR
+
+GQA = harness.load_module(BENCH_DIR, "layouts", "dense_gqa")
 
 
 def cfg(name):
@@ -34,32 +39,32 @@ def test_model_flops_per_token_by_hand():
     # + 14 * 64 * 896 = 802,816 (o) + 3 * 896 * 4864 = 13,074,432 (MLP)
     # = 14,909,440; 24 layers = 357,826,560; head 896 * 151,936 =
     # 136,134,656; attention 24 * 4 * 14 * 64 = 86,016 per key
-    assert roofline.matmul_params(QWEN) == 493_961_216
-    assert roofline.token_flops(QWEN, 1000) == 987_922_432 + 86_016_000
+    assert GQA.matmul_params(QWEN) == 493_961_216
+    assert GQA.token_flops(QWEN, 1000) == 987_922_432 + 86_016_000
     # internlm2-20b share, per layer: 6144 * (12 + 2 * 2) * 128 =
     # 12,582,912 + 1536 * 6144 = 9,437,184 + 3 * 6144 * 16384 =
     # 301,989,888 = 324,009,984; 8 layers = 2,592,079,872; head
     # 6144 * 23136 = 142,147,584; attention 8 * 4 * 12 * 128 = 49,152
-    assert roofline.matmul_params(INTERN) == 2_734_227_456
-    assert roofline.token_flops(INTERN, 300) == 5_468_454_912 + 49_152 * 300
-    assert roofline.matmul_params(INTERN, head=False) == 2_592_079_872
+    assert GQA.matmul_params(INTERN) == 2_734_227_456
+    assert GQA.token_flops(INTERN, 300) == 5_468_454_912 + 49_152 * 300
+    assert GQA.matmul_params(INTERN, head=False) == 2_592_079_872
 
 
 def test_prefill_chunk_flops_by_hand():
     # 3 tokens from position 10: keys 11 + 12 + 13 = 36
     want = 2 * 2_592_079_872 * 3 + 8 * 4 * 36 * 12 * 128
-    assert roofline.chunk_flops(INTERN, 10, 3) == want
+    assert GQA.chunk_flops(INTERN, 10, 3) == want
 
 
 def test_decode_launch_by_hand():
     # qwen2 page: 16 tokens * 2 KV heads * 64 * 2 B = 4096 B; contexts 17
     # and 32 hold 2 pages each, read as K and V: 2 * 4 * 4096 = 32,768;
     # queries and outputs 2 * 2 * 14 * 64 * 2 B = 7,168
-    w = roofline.decode_attention(QWEN, 16, [17, 32])
+    w = GQA.decode_attention(QWEN, 16, [17, 32])
     assert w.flops == 4 * (17 + 32) * 14 * 64
     assert w.bytes == 32_768 + 7_168
     # internlm2 share page: 16 * 2 * 128 * 2 B = 8192 B; context 1 -> 1 page
-    w = roofline.decode_attention(INTERN, 16, [1])
+    w = GQA.decode_attention(INTERN, 16, [1])
     assert (w.flops, w.bytes) == (4 * 12 * 128, 2 * 8192 + 2 * 12 * 128 * 2)
 
 
@@ -67,7 +72,7 @@ def test_prefill_launch_by_hand():
     # chunks (start 0, 16 tokens): keys 16 * 17 / 2 = 136; (100, 28):
     # 28 * 100 + 28 * 29 / 2 = 3206. Pages: 1 and 8, as K and V:
     # 2 * 9 * 4096 = 73,728; queries and outputs 44 * 2 * 896 * 2 = 157,696
-    w = roofline.prefill_attention(QWEN, 16, [(0, 16), (100, 28)])
+    w = GQA.prefill_attention(QWEN, 16, [(0, 16), (100, 28)])
     assert w.flops == 4 * 3342 * 14 * 64
     assert w.bytes == 73_728 + 157_696
 
