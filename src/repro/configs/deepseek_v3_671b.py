@@ -1,8 +1,11 @@
-"""deepseek-v3-671b [moe]: 61L d=7168 128H MLA, 1 shared + 256 routed top-8,
-first 3 layers dense (d_ff 18432), MTP depth 1, vocab 129280.
+"""deepseek-v3-671b [moe]: 61L d=7168 128H MLA, 1 shared + 256 routed top-8
+(sigmoid, noaux_tc: 8 groups, top-4 groups, scale 2.5), first 3 layers dense
+(d_ff 18432), YaRN RoPE (factor 40 over 4096), MTP depth 1, vocab 129280.
 [arXiv:2412.19437; hf-verified]"""
 
-from repro.models.config import MLAConfig, ModelConfig, MoEConfig
+from repro.models.config import MLAConfig, ModelConfig, MoEConfig, YaRNConfig
+
+ROUTING = dict(scoring="sigmoid", routed_scaling_factor=2.5)
 
 CONFIG = ModelConfig(
     name="deepseek-v3-671b",
@@ -14,9 +17,13 @@ CONFIG = ModelConfig(
     d_ff=2048,
     vocab_size=129280,
     moe=MoEConfig(num_experts=256, top_k=8, d_expert=2048,
-                  num_shared_experts=1, first_k_dense=3, d_ff_dense=18432),
+                  num_shared_experts=1, first_k_dense=3, d_ff_dense=18432,
+                  n_group=8, topk_group=4, **ROUTING),
     mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
+    rope_scaling=YaRNConfig(factor=40.0, original_max_position_embeddings=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                            mscale_all_dim=1.0),
     mtp_depth=1,
     param_dtype="bfloat16",   # 671B: bf16 params + 8-bit Adam (optimizer.py)
     seq_shard_activations=True,
@@ -28,7 +35,9 @@ def smoke_config() -> ModelConfig:
         name="deepseek-v3-smoke", family="moe", num_layers=5, d_model=64,
         num_heads=4, num_kv_heads=4, d_ff=48, vocab_size=256,
         moe=MoEConfig(num_experts=8, top_k=2, d_expert=32,
-                      num_shared_experts=1, first_k_dense=1, d_ff_dense=48),
+                      num_shared_experts=1, first_k_dense=1, d_ff_dense=48,
+                      n_group=4, topk_group=2, **ROUTING),
         mla=MLAConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
                       qk_rope_head_dim=8, v_head_dim=16),
+        rope_scaling=CONFIG.rope_scaling,
         mtp_depth=1)

@@ -321,3 +321,116 @@ def paged_attention(q, k_pool, v_pool, page_table, lens, *,
         interpret=interpret,
     )(page_table, lens, q, k_pool, v_pool)
     return out
+
+
+# -- latent (MLA) decode ------------------------------------------------------
+
+def _latent_kernel(table_ref, lens_ref, layer_ref, ql_ref, qp_ref, kpe_ref,
+                   ckv_ref, o_ref, m_ref, l_ref, acc_ref, *, page_size: int,
+                   rope: int, scale: float):
+    """One page of one sequence's absorbed MLA decode: every query head
+    reads the page's shared latent ``ckv`` [ps, r] as key (with the rope
+    key) and as value. The rope-key page is stored folded
+    ([ps / f, f * rope]; token ``i * ps / f + j`` in row ``j``, lanes
+    ``i * rope ..``); the query's rope part comes repeated ``f`` times, so
+    one product against the page's rows, each kept to its token's lanes,
+    scores every token with no relayout."""
+    del layer_ref                                    # used by the index maps
+    b = pl.program_id(0)
+    pi = pl.program_id(1)
+    np_ = pl.num_programs(1)
+
+    @pl.when(pi == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    seq_len = lens_ref[b]
+    page_start = pi * page_size
+
+    @pl.when(page_start < seq_len)
+    def _compute():
+        ql = ql_ref[0]                               # [H, r]
+        qp = qp_ref[0]                               # [H, f * rope]
+        ckv = ckv_ref[0]                             # [ps, r]
+        kpe = kpe_ref[0].astype(jnp.float32)         # [ps / f, f * rope]
+        rows, width = kpe.shape
+        if rows != page_size:
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+            kpe = jnp.concatenate(
+                [jnp.where(lane // rope == i, kpe, 0.0)
+                 for i in range(page_size // rows)], axis=0)  # [ps, f*rope]
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql, ckv, dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qp, kpe.astype(qp.dtype), dims,
+                                   preferred_element_type=jnp.float32))
+        s = s * scale                                # [H, ps]
+        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < seq_len, s, NEG_INF)
+
+        m_prev = m_ref[...]                          # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(p.astype(ckv.dtype), ckv,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv     # [H, r]
+        m_ref[...] = m_new
+
+    @pl.when(pi == np_ - 1)
+    def _finish():
+        l = l_ref[...]
+        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_latent_attention(q_lat, q_pe, kpe_pool, ckv_pool, page_table, lens,
+                           layer, *, scale: float, interpret: bool = False):
+    """Absorbed MLA decode over the stacked latent pools: q_lat [B,H,r];
+    q_pe [B,H,rope]; kpe_pool [L,P,ps/f,f*rope] (folded rope keys);
+    ckv_pool [L,P,ps,r]; page_table [B,mp] (pad with page 0); lens [B];
+    layer traced scalar -> [B,H,r], the latent of each head's output.
+
+    ``layer`` is prefetched with the table, and the pools' index maps pick
+    the layer's pages, so no layer of the pool is sliced out first."""
+    b, h, r = q_lat.shape
+    rope = q_pe.shape[-1]
+    rows, width = kpe_pool.shape[2:]
+    ps = ckv_pool.shape[2]
+    mp = page_table.shape[1]
+    qp = jnp.tile(q_pe, (1, 1, width // rope))       # [B, H, f * rope]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, mp),
+        in_specs=[
+            pl.BlockSpec((1, h, r), lambda b, p, tbl, ln, ly: (b, 0, 0)),
+            pl.BlockSpec((1, h, width), lambda b, p, tbl, ln, ly: (b, 0, 0)),
+            pl.BlockSpec((None, 1, rows, width),
+                         lambda b, p, tbl, ln, ly: (ly[0], tbl[b, p], 0, 0)),
+            pl.BlockSpec((None, 1, ps, r),
+                         lambda b, p, tbl, ln, ly: (ly[0], tbl[b, p], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, h, r), lambda b, p, tbl, ln, ly: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),         # m
+            pltpu.VMEM((h, 1), jnp.float32),         # l
+            pltpu.VMEM((h, r), jnp.float32),         # acc
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, page_size=ps, rope=rope,
+                               scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, r), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(page_table, lens, jnp.asarray(layer, jnp.int32).reshape(1),
+      q_lat, qp, kpe_pool, ckv_pool)

@@ -121,3 +121,79 @@ def paged_prefill_attention_ref(q, k_pool, v_pool, page_table, q_start):
         q[None], k_pool, v_pool, page_table[None],
         jnp.asarray(q_start, jnp.int32).reshape(1))
     return out[0]
+
+
+# -- latent (MLA) pools ----------------------------------------------------------
+
+def unfold_rope_page(kpe_page, rope: int):
+    """A folded rope-key page [..., ps/f, f*rope] (token ``i * ps/f + j`` in
+    row ``j``, lanes ``i * rope ..``) as [..., ps, rope] in token order."""
+    *lead, rows, width = kpe_page.shape
+    f = width // rope
+    x = kpe_page.reshape(*lead, rows, f, rope)
+    x = jnp.swapaxes(x, -3, -2)
+    return x.reshape(*lead, rows * f, rope)
+
+
+def _latent_walk(q_lat, q_pe, kpe_pool, ckv_pool, page_table, layer, scale,
+                 mask_fn):
+    """Online-softmax page walk of an absorbed MLA attention. q_lat
+    [B,R,r], q_pe [B,R,rope] float32 query rows; ``mask_fn(pos)`` maps key
+    positions [B,ps] to [B,R,ps]. Returns [B,R,r] float32."""
+    b, rr, r = q_lat.shape
+    rope = q_pe.shape[-1]
+    ps = ckv_pool.shape[2]
+    m = jnp.full((b, rr, 1), NEG_INF, jnp.float32)
+    l = jnp.zeros((b, rr, 1), jnp.float32)
+    acc = jnp.zeros((b, rr, r), jnp.float32)
+    for pi in range(page_table.shape[1]):
+        # gathered with the layer index: no copy of the layer's pool
+        ckv = ckv_pool[layer, page_table[:, pi]].astype(
+            jnp.float32)                                         # [B,ps,r]
+        kpe = unfold_rope_page(kpe_pool[layer, page_table[:, pi]],
+                               rope).astype(jnp.float32)         # [B,ps,rope]
+        s = (jnp.einsum("bqr,bpr->bqp", q_lat, ckv)
+             + jnp.einsum("bqe,bpe->bqp", q_pe, kpe)) * scale
+        pos = pi * ps + jnp.arange(ps)[None, :]
+        s = jnp.where(mask_fn(pos), s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum("bqp,bpr->bqr", p, ckv)
+        m = m_new
+    return acc / jnp.where(l == 0.0, 1.0, l)
+
+
+def paged_latent_attention_ref(q_lat, q_pe, kpe_pool, ckv_pool, page_table,
+                               lens, layer, scale):
+    """Decode oracle of ``kernel.paged_latent_attention``: q_lat [B,H,r],
+    q_pe [B,H,rope]; pools [L,P,...] stacked; query b sees positions
+    < lens[b] of layer ``layer`` through its table row. -> [B,H,r]."""
+    h = q_lat.shape[1]
+
+    def mask(pos):
+        ok = pos < lens[:, None]
+        return jnp.broadcast_to(ok[:, None, :], (ok.shape[0], h, ok.shape[1]))
+
+    out = _latent_walk(q_lat.astype(jnp.float32), q_pe.astype(jnp.float32),
+                       kpe_pool, ckv_pool, page_table, layer, scale, mask)
+    return out.astype(q_lat.dtype)
+
+
+def paged_latent_prefill_ref(q_lat, q_pe, kpe_pool, ckv_pool, page_table,
+                             q_start, layer, scale):
+    """Prefill over the latent pools: B sequences' query chunks q_lat
+    [B,T,H,r], q_pe [B,T,H,rope], query (b, t) at position
+    ``q_start[b] + t`` seeing positions <= its own through table row b
+    (its chunk's rows written before the call). -> [B,T,H,r]."""
+    b, t, h, r = q_lat.shape
+    qpos = q_start[:, None] + jnp.repeat(jnp.arange(t), h)[None, :]
+
+    def mask(pos):                                   # [B,ps] -> [B,T*H,ps]
+        return pos[:, None, :] <= qpos[:, :, None]
+
+    out = _latent_walk(q_lat.reshape(b, t * h, r).astype(jnp.float32),
+                       q_pe.reshape(b, t * h, -1).astype(jnp.float32),
+                       kpe_pool, ckv_pool, page_table, layer, scale, mask)
+    return out.reshape(b, t, h, r).astype(q_lat.dtype)

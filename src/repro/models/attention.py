@@ -135,7 +135,7 @@ CHUNK_SCAN_THRESHOLD = 4  # python-unrolled up to here; lax.scan beyond
 
 
 def chunked_mha(q, k, v, qpos, kpos, window, causal, compute_dtype,
-                chunk: int = 512, scores_dtype=jnp.float32):
+                chunk: int = 512, scores_dtype=jnp.float32, scale=None):
     """Full-sequence attention, chunked over queries (memory-bounded
     reference of the flash kernel: the live score buffer is
     [B, nq, chunk, S_kv] instead of [B, nq, S, S]).
@@ -143,12 +143,14 @@ def chunked_mha(q, k, v, qpos, kpos, window, causal, compute_dtype,
     q/k/v are HEAD-ALIGNED ([B,S,n,h] with identical head counts — callers
     repeat KV for GQA so tensor-parallel head sharding propagates without
     resharding). Chunks beyond CHUNK_SCAN_THRESHOLD run under lax.scan; the
-    body is exposed as a roofline fragment (lm.fragments)."""
+    body is exposed as a roofline fragment (lm.fragments). ``scale``
+    multiplies the scores (default ``1 / sqrt(head_dim)``)."""
     b, s, nq, h = q.shape
     nc = attn_q_chunks(s, chunk)
     if nc == 1:
         bias = _window_bias(qpos, kpos, window, causal)
-        return _mha_one_chunk(q, k, v, bias, compute_dtype, scores_dtype)
+        return _mha_one_chunk(q, k, v, bias, compute_dtype, scores_dtype,
+                              scale)
     pad = nc * chunk - s
     if pad:
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -160,7 +162,7 @@ def chunked_mha(q, k, v, qpos, kpos, window, causal, compute_dtype,
     one_chunk = jax.checkpoint(
         functools.partial(_chunk_with_bias, window=window, causal=causal,
                           compute_dtype=compute_dtype,
-                          scores_dtype=scores_dtype))
+                          scores_dtype=scores_dtype, scale=scale))
     hv = v.shape[-1]   # value head dim can differ from qk dim (MLA)
     if nc <= CHUNK_SCAN_THRESHOLD:
         outs = []
@@ -182,13 +184,13 @@ def chunked_mha(q, k, v, qpos, kpos, window, causal, compute_dtype,
 
 
 def _chunk_with_bias(q, qpos, k, v, kpos, *, window, causal,
-                     compute_dtype, scores_dtype=jnp.float32):
+                     compute_dtype, scores_dtype=jnp.float32, scale=None):
     bias = _window_bias(qpos, kpos, window, causal)
-    return _mha_one_chunk(q, k, v, bias, compute_dtype, scores_dtype)
+    return _mha_one_chunk(q, k, v, bias, compute_dtype, scores_dtype, scale)
 
 
 def _mha_one_chunk(q, k, v, bias, compute_dtype,
-                   scores_dtype=jnp.float32):
+                   scores_dtype=jnp.float32, scale=None):
     """q [B,c,n,h], k/v [B,T,n,h], bias [B,c,T] -> [B,c,n,h].
 
     scores_dtype=bfloat16 halves the S^2 score-tensor traffic (the dot still
@@ -198,7 +200,8 @@ def _mha_one_chunk(q, k, v, bias, compute_dtype,
     scores = jax.lax.dot_general(
         q, k, (((3,), (3,)), ((0, 2), (0, 2))),
         preferred_element_type=jnp.float32)            # [B,n,c,T] fp32 acc
-    scores = (scores / np.sqrt(h)).astype(scores_dtype)
+    scores = (scores / np.sqrt(h) if scale is None
+              else scores * scale).astype(scores_dtype)
     scores = scores + bias[:, None, :, :].astype(scores_dtype)
     probs = jax.nn.softmax(scores, axis=-1).astype(compute_dtype)
     return jnp.einsum("bnst,btnh->bsnh", probs, v)
@@ -305,6 +308,18 @@ def decode_attention(cfg: ModelConfig, p: Params, x, cache, position,
 # MLA (DeepSeek-V3): compressed-latent attention
 # ---------------------------------------------------------------------------
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale: ``(qk_nope + qk_rope) ** -0.5``, times
+    ``mscale(factor, mscale_all_dim) ** 2`` under YaRN (DeepSeek-V3: 1.874
+    at factor 40)."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= layers.yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_qkv(cfg, p, x, positions):
     m = cfg.mla
     cdt = dtype_of(cfg.compute_dtype)
@@ -315,14 +330,46 @@ def _mla_qkv(cfg, p, x, positions):
     q = (cq @ p["wq_b"].astype(cdt)).reshape(
         b, s, nq, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_pe = layers.apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = layers.apply_rope(q_pe, positions, cfg.rope_theta,
+                             cfg.rope_scaling)
 
     kv = x.astype(cdt) @ p["wkv_a"].astype(cdt)
     ckv, k_pe = jnp.split(kv, [m.kv_lora_rank], axis=-1)
     ckv = layers.rmsnorm(ckv, p["kv_norm"], cfg.rms_eps)
     k_pe = layers.apply_rope(k_pe[:, :, None, :], positions,
-                             cfg.rope_theta)[:, :, 0, :]
+                             cfg.rope_theta, cfg.rope_scaling)[:, :, 0, :]
     return q_nope, q_pe, ckv, k_pe
+
+
+def _mla_w_ukv(cfg, p):
+    """``wkv_b`` split into the key and value up-projections, each
+    [kv_lora_rank, heads, dim]."""
+    m = cfg.mla
+    wkv_b = p["wkv_b"].astype(dtype_of(cfg.compute_dtype)).reshape(
+        m.kv_lora_rank, cfg.num_heads, m.qk_nope_head_dim + m.v_head_dim)
+    return wkv_b[:, :, :m.qk_nope_head_dim], wkv_b[:, :, m.qk_nope_head_dim:]
+
+
+def mla_latent_query(cfg: ModelConfig, p: Params, x, positions):
+    """The inputs of an absorbed MLA attention over a latent cache, for
+    ``x`` [B,S,d] at ``positions`` [B,S]: ``q_lat`` [B,S,H,r] (the no-rope
+    query through ``W_UK``), ``q_pe`` [B,S,H,rope], and the tokens' cache
+    rows, the normalised latent ``ckv`` [B,S,r] and the rope key ``k_pe``
+    [B,S,rope]."""
+    q_nope, q_pe, ckv, k_pe = _mla_qkv(cfg, p, x, positions)
+    w_uk, _ = _mla_w_ukv(cfg, p)
+    q_lat = jnp.einsum("bsnh,rnh->bsnr", q_nope, w_uk)
+    return q_lat, q_pe, ckv, k_pe
+
+
+def mla_latent_out(cfg: ModelConfig, p: Params, out_lat):
+    """Attention output [B,S,d] from its latent ``out_lat`` [B,S,H,r]:
+    through ``W_UV``, then ``wo``."""
+    cdt = dtype_of(cfg.compute_dtype)
+    b, s = out_lat.shape[:2]
+    _, w_uv = _mla_w_ukv(cfg, p)
+    out = jnp.einsum("bsnr,rnh->bsnh", out_lat.astype(cdt), w_uv)
+    return out.reshape(b, s, -1) @ p["wo"].astype(cdt)
 
 
 def mla_attention(cfg: ModelConfig, p: Params, x, positions, window):
@@ -344,7 +391,8 @@ def mla_attention(cfg: ModelConfig, p: Params, x, positions, window):
                                   (b, s, nq, m.qk_rope_head_dim))], axis=-1)
     out = chunked_mha(q_full, k_full, v, positions, positions, window,
                       True, cdt,
-                      scores_dtype=dtype_of(cfg.attn_scores_dtype))
+                      scores_dtype=dtype_of(cfg.attn_scores_dtype),
+                      scale=mla_softmax_scale(cfg))
     out = out.reshape(b, s, nq * m.v_head_dim) @ p["wo"].astype(cdt)
     return out, (ckv, k_pe)
 
@@ -370,14 +418,11 @@ def mla_decode_attention(cfg: ModelConfig, p: Params, x, cache, position,
     cpos = jax.lax.dynamic_update_slice_in_dim(cache["pos"], pos_b, slot,
                                                axis=1)
 
-    wkv_b = p["wkv_b"].astype(cdt).reshape(
-        m.kv_lora_rank, nq, m.qk_nope_head_dim + m.v_head_dim)
-    w_uk = wkv_b[:, :, :m.qk_nope_head_dim]       # [r, n, hk]
-    w_uv = wkv_b[:, :, m.qk_nope_head_dim:]       # [r, n, hv]
+    w_uk, w_uv = _mla_w_ukv(cfg, p)               # [r, n, hk], [r, n, hv]
 
     # absorb K up-projection into the query
     q_lat = jnp.einsum("bsnh,rnh->bsnr", q_nope, w_uk)       # [b,1,n,r]
-    scale = 1.0 / np.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bsnr,btr->bnst", q_lat.astype(jnp.float32),
                          ckv.astype(jnp.float32))
               + jnp.einsum("bsnh,bth->bnst", q_pe.astype(jnp.float32),

@@ -17,6 +17,25 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.001
     router_scale: bool = True     # normalize top-k gate weights to sum 1
+    # DeepSeek-V3 routing (``moe.held_moe_apply``): sigmoid scores with a
+    # score-correction bias that chooses but does not weight, top-k within
+    # the best ``topk_group`` of ``n_group`` expert groups, weights times
+    # ``routed_scaling_factor``. The capacity path (``moe.moe_apply``)
+    # reads none of these.
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # the experts this chip holds: ``held_count`` experts from
+    # ``held_first`` (0 = all of them); the router still scores all
+    # ``num_experts``
+    held_first: int = 0
+    held_count: int = 0
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.held_first, self.held_count or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -27,6 +46,21 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN RoPE scaling as DeepSeek-V3 states it (``rope_scaling`` of its
+    config.json; arXiv:2309.00071): frequencies interpolated between
+    ``beta_slow`` and ``beta_fast`` rotations over
+    ``original_max_position_embeddings``, and an attention scale
+    ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +97,7 @@ class ModelConfig:
     act: Literal["silu", "gelu", "geglu"] = "silu"
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     rope_theta: float = 1e4
+    rope_scaling: YaRNConfig | None = None
     rms_eps: float = 1e-6
     max_seq: int = 131072
     # sliding-window pattern: window size for "local" layers; every

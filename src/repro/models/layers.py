@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -96,16 +97,55 @@ def mlp_apply(cfg, p: Params, x):
 
 # -- rotary embeddings ------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 for no
+    scaling): 1.3689 at DeepSeek-V3's factor 40."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_dim(rotations: float, dim: int, theta: float, max_pos: int):
+    """The rotary dimension whose wavelength fits ``rotations`` turns into
+    ``max_pos`` positions."""
+    return (dim * math.log(max_pos / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def yarn_freqs(head_dim: int, theta: float, ys) -> np.ndarray:
+    """YaRN inverse frequencies (``ys``: ``config.YaRNConfig``), as
+    DeepSeek-V3 computes them: dimensions that turn more than
+    ``beta_fast`` times over ``original_max_position_embeddings`` keep
+    their frequency, those that turn fewer than ``beta_slow`` times take
+    it divided by ``factor``, with a linear ramp between."""
+    extra = rope_freqs(head_dim, theta)
+    inter = extra / ys.factor
+    orig = ys.original_max_position_embeddings
+    low = max(math.floor(_yarn_dim(ys.beta_fast, head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_dim(ys.beta_slow, head_dim, theta, orig)),
+               head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling=None):
+    if scaling is not None:
+        return yarn_freqs(head_dim, theta, scaling)
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, H, D]; positions: broadcastable to [..., S]."""
+def apply_rope(x, positions, theta: float, scaling=None):
+    """x: [..., S, H, D]; positions: broadcastable to [..., S]. With
+    ``scaling`` (a ``YaRNConfig``) the frequencies are YaRN's and cos/sin
+    carry ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), dtype=jnp.float32)
+    freqs = jnp.asarray(rope_freqs(d, theta, scaling), dtype=jnp.float32)
     ang = positions[..., :, None, None].astype(jnp.float32) * freqs  # [...,S,1,D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

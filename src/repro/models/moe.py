@@ -1,4 +1,5 @@
-"""Mixture-of-Experts: top-k router + capacity-grouped sorted dispatch.
+"""Mixture-of-Experts: top-k router + capacity-grouped sorted dispatch, and
+a dropless layer that holds a share of the experts (``held_moe_apply``).
 
 Dispatch is ROW-LOCAL: routing/sort/capacity run independently per batch row
 (vmapped sort), so under pjit everything stays batch-sharded — no global
@@ -58,7 +59,10 @@ def capacity(tokens_per_row: int, cfg: ModelConfig) -> int:
 def padded_experts(cfg: ModelConfig, align: int = 16) -> int:
     """Expert tensors are padded to a multiple of the model-axis size so the
     expert dim shards cleanly (granite's 40 -> 48; dead experts are never
-    routed to — the router stays at the true expert count)."""
+    routed to — the router stays at the true expert count). A layer that
+    holds a share of the experts has that share's count."""
+    if cfg.moe.held_count:
+        return cfg.moe.held_count
     e = cfg.moe.num_experts
     if e % align == 0 or e < align:
         return e
@@ -82,6 +86,9 @@ def moe_params(key, cfg: ModelConfig) -> Params:
         "w_up": experts(ks[2], d, f),
         "w_down": experts(ks[3], f, d),
     }
+    if m.scoring == "sigmoid":
+        # the score-correction bias: it chooses experts, never weights them
+        p["router_bias"] = jnp.zeros((m.num_experts,), jnp.float32)
     if m.num_shared_experts:
         p["shared"] = layers.mlp_params(
             ks[4], cfg, d, f * m.num_shared_experts)
@@ -157,3 +164,61 @@ def moe_apply(cfg: ModelConfig, p: Params, x):
     aux = e * jnp.sum(frac_tokens * mean_prob, axis=-1).mean() \
         * m.aux_loss_weight
     return out.reshape(b, s, d), aux
+
+
+# -- a share of the experts, dropless (DeepSeek-V3 serving) -------------------
+
+def route_grouped(cfg: ModelConfig, logits, bias):
+    """DeepSeek-V3 routing over all ``num_experts`` experts for ``logits``
+    [T, E] (float32): sigmoid scores; the correction ``bias`` [E] added for
+    the choice only; each of ``n_group`` groups scored by the sum of its two
+    best biased scores; the best ``topk_group`` groups kept, and the
+    ``top_k`` best biased scores within them chosen. Returns (experts
+    [T, K] int32, weights [T, K] float32): the unbiased scores of the
+    chosen, normalised to sum to 1 (``router_scale``), times
+    ``routed_scaling_factor``."""
+    m = cfg.moe
+    t, e = logits.shape
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores + bias.astype(jnp.float32)
+    groups = choice.reshape(t, m.n_group, e // m.n_group)
+    group_score = jax.lax.top_k(groups, 2)[0].sum(-1)          # [T, G]
+    _, keep = jax.lax.top_k(group_score, m.topk_group)
+    kept = jax.nn.one_hot(keep, m.n_group, dtype=jnp.bool_).any(1)
+    choice = jnp.where(jnp.repeat(kept, e // m.n_group, axis=1), choice,
+                       -jnp.inf)
+    _, experts = jax.lax.top_k(choice, m.top_k)
+    w = jnp.take_along_axis(scores, experts, -1)
+    if m.router_scale:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return experts, w * m.routed_scaling_factor
+
+
+def held_moe_apply(cfg: ModelConfig, p: Params, x):
+    """The expert layer of one chip that holds ``cfg.moe.held`` of the
+    experts: routes ``x`` [T, d] over all of them (``route_grouped``,
+    scores in float32) and drops no token; computes the held experts'
+    terms, each weighted by its gate (0 for a token that did not choose
+    it), plus the shared expert. The other chips' terms are theirs to add.
+
+    The held experts run as one dense pass over every token: at decode
+    batches their weights are read once whatever the tokens, and that read
+    bounds the layer. Returns (out [T, d], held [T] int32: how many of
+    each token's ``top_k`` choices fell on a held expert)."""
+    m = cfg.moe
+    cdt = dtype_of(cfg.compute_dtype)
+    first, count = m.held
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    experts, w = route_grouped(cfg, logits, p["router_bias"])
+    local = jax.nn.one_hot(experts - first, count, dtype=jnp.float32)
+    gates = jnp.einsum("tk,tke->te", w, local)                 # [T, held]
+    xc = x.astype(cdt)
+    act = layers.act_fn(cfg.act)
+    h = act(jnp.einsum("td,edf->etf", xc, p["w_gate"].astype(cdt))) \
+        * jnp.einsum("td,edf->etf", xc, p["w_up"].astype(cdt))
+    y = jnp.einsum("etf,efd->etd", h, p["w_down"].astype(cdt))
+    out = jnp.einsum("te,etd->td", gates, y.astype(jnp.float32))
+    if m.num_shared_experts:
+        out = out + layers.mlp_apply(cfg, p["shared"], xc).astype(jnp.float32)
+    return out.astype(cdt), local.sum((1, 2)).astype(jnp.int32)

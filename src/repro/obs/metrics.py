@@ -184,10 +184,24 @@ class _Family:
 
 class MetricsRegistry:
     """Registry of metric families; registration is idempotent by name
-    (re-registering returns the existing family, schema must match)."""
+    (re-registering returns the existing family, schema must match).
+
+    A counter whose counts accrue elsewhere (on a device, where reading
+    them every step would stall the host) registers a collector with
+    :meth:`on_read`; every read of the registry runs the collectors first,
+    and each folds what accrued into its families."""
 
     def __init__(self):
         self._families: dict[str, _Family] = {}
+        self._collectors: list = []
+
+    def on_read(self, collect) -> None:
+        """Run ``collect()`` before every read (``get``, the exports)."""
+        self._collectors.append(collect)
+
+    def _collect(self) -> None:
+        for collect in self._collectors:
+            collect()
 
     def _register(self, name: str, help_text: str, kind: str,
                   labelnames, buckets=None) -> _Family:
@@ -219,14 +233,17 @@ class MetricsRegistry:
                               buckets)
 
     def get(self, name: str) -> _Family | None:
+        self._collect()
         return self._families.get(name)
 
     def prometheus_text(self) -> str:
+        self._collect()
         lines: list[str] = []
         for fam in self._families.values():
             lines.extend(fam.prometheus_lines())
         return "\n".join(lines) + "\n" if lines else ""
 
     def snapshot(self) -> dict:
+        self._collect()
         return {name: fam.snapshot()
                 for name, fam in self._families.items()}

@@ -107,17 +107,39 @@ def paged_kv_geometry(cfg, page_size: int) -> PageGeometry:
         k_block=block, v_block=block, shareable=True)
 
 
+# lanes of a TPU vector register: the minor axis of a block that the chip
+# stores in its own row-major tiles
+LANES = 128
+
+
+def rope_fold(page_size: int, rope: int) -> int:
+    """Tokens of a rope-key page that share one row of ``LANES``: the
+    largest divisor of ``page_size`` whose keys fit in it (2 at rope 64)."""
+    return max(f for f in range(1, page_size + 1)
+               if page_size % f == 0 and f * rope <= max(LANES, rope))
+
+
 def mla_latent_geometry(cfg, page_size: int) -> PageGeometry:
     """MLA latent-compressed K/V (arXiv:2412.19437): per token the cache
     holds one shared rope key (``qk_rope_head_dim``) in the k array and
     one latent vector (``kv_lora_rank``) in the v array — asymmetric
-    widths, far below ``2 * nkv * hd``."""
+    widths, far below ``2 * nkv * hd``.
+
+    Blocks are 2-D so that the chip keeps each pool in its row-major
+    tiles with no padding, and a Pallas block of one page reads it in
+    place: the latent page is ``[page_size, kv_lora_rank]``; the rope-key
+    page is folded to ``[page_size / f, f * qk_rope_head_dim]``
+    (:func:`rope_fold`), token ``i * page_size / f + j`` in row ``j``. A
+    ``[page_size, 1, 64]`` block pads, or is laid out with pages on the
+    lanes, where a page-by-page read copies the whole pool first."""
     assert cfg.mla is not None, f"{cfg.name}: no MLA config"
+    rope = cfg.mla.qk_rope_head_dim
+    f = rope_fold(page_size, rope)
     return PageGeometry(
         kind="mla_latent", page_size=page_size, num_layers=cfg.num_layers,
         itemsize=jnp.dtype(cfg.compute_dtype).itemsize,
-        k_block=(page_size, 1, cfg.mla.qk_rope_head_dim),
-        v_block=(page_size, 1, cfg.mla.kv_lora_rank), shareable=True)
+        k_block=(page_size // f, f * rope),
+        v_block=(page_size, cfg.mla.kv_lora_rank), shareable=True)
 
 
 def ssm_state_geometry(cfg) -> PageGeometry:

@@ -77,7 +77,12 @@ def _write_rows(k_pool, v_pool, layer, page_ids, slots, k_rows, v_rows,
     dynamic-update-slice writes in the pool's own layout
     (tests/test_chip_compile.py checks the program). A block that would run
     past the last row is moved back to end there, as ``dynamic_slice``
-    clamps, and writes its first rows a second time."""
+    clamps, and writes its first rows a second time.
+
+    A row narrower than the pool's minor axis goes to a folded page
+    ``[L, P, page_size / f, f * w]`` (the MLA rope keys,
+    ``geometry.mla_latent_geometry``): slot ``s`` at row ``s % (page_size /
+    f)``, lanes ``(s // (page_size / f)) * w``."""
     page_ids = jax.lax.dynamic_slice_in_dim(page_ids, start, block)
     slots = jax.lax.dynamic_slice_in_dim(slots, start, block)
     out = []
@@ -85,11 +90,18 @@ def _write_rows(k_pool, v_pool, layer, page_ids, slots, k_rows, v_rows,
         if rows.ndim == pool.ndim - 2:           # [N, *row]: one layer
             rows = rows[None]
         rows = jax.lax.dynamic_slice_in_dim(rows, start, block, axis=1)
-        size = (rows.shape[0], 1, 1) + pool.shape[3:]
+        w = rows.shape[-1]
+        if w != pool.shape[-1]:                  # folded page
+            size = (rows.shape[0], 1, 1, w)
+            at = lambda i: (layer, page_ids[i], slots[i] % pool.shape[2],
+                            slots[i] // pool.shape[2] * w)
+        else:
+            size = (rows.shape[0], 1, 1) + pool.shape[3:]
+            at = lambda i: ((layer, page_ids[i], slots[i])
+                            + (0,) * (pool.ndim - 3))
         for i in range(block):
-            at = (layer, page_ids[i], slots[i]) + (0,) * (pool.ndim - 3)
             pool = jax.lax.dynamic_update_slice(
-                pool, rows[:, i].reshape(size).astype(pool.dtype), at)
+                pool, rows[:, i].reshape(size).astype(pool.dtype), at(i))
         out.append(pool)
     return tuple(out)
 

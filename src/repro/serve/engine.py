@@ -1,4 +1,5 @@
-"""Batched serving engine over the BWAP page pool (dense GQA archs).
+"""Batched serving engine over the BWAP page pool (dense GQA archs, and
+MLA with a held share of its MoE experts: DeepSeek-V3).
 
 CPU-runnable end-to-end: priority continuous batching through the request
 scheduler (admission, chunked prefill, preemption with KV swap to slow
@@ -15,6 +16,8 @@ device trace can put its idle time down to what the host was doing.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import time
 from typing import Sequence
 
@@ -23,9 +26,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.paged_attention import ops as paged_ops
+from repro.models import attention, moe
 from repro.models import layers as L
 from repro.models.config import ModelConfig
-from repro.models.lm import LM
+from repro.models.lm import LM, make_groups
 from repro.obs.trace import SPAN_PREFIX, span
 from repro.placement.fabric import as_view
 from repro.scheduler.scheduler import Request, RequestScheduler
@@ -67,11 +71,91 @@ def attention_impl(platform: str) -> str:
             f"(supported: {sorted(_ATTENTION_IMPL)})") from None
 
 
+# -- the MLA body's programs: each takes a layer group's stacked weights and
+# the layer's index in it (None for a group held as a list of layers), so
+# no layer's weights are sliced out before a program reads them
+
+def _pick(tree, j):
+    return tree if j is None else jax.tree.map(lambda a: a[j], tree)
+
+
+def latent_table_width(mp: int) -> int:
+    """The page-table width the latent decode kernel runs at for tables
+    ``mp`` pages wide: a multiple of 8 up to 64, then the next power of
+    two. The kernel compiles per width and a batch's tables grow a page
+    every ``page_size`` steps, so widths come in few sizes, and the grid
+    steps spent past ``mp`` stay few where most steps run; the columns
+    past ``mp`` hold page 0, which the kernel masks and fetches once."""
+    return -(-mp // 8) * 8 if mp <= 64 else 1 << (mp - 1).bit_length()
+
+
+def _repeat_last(n: int, *rows):
+    """Host arrays padded to ``n`` entries along the first axis by
+    repeating their last entry."""
+    return tuple(np.concatenate([a, np.repeat(a[-1:], n - len(a), axis=0)])
+                 for a in (np.asarray(r) for r in rows))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _latent_in(cfg, norm1, attn, j, x, positions, rows):
+    """Norm, MLA projections and ``W_UK`` absorption of ``x`` [B,S,d]: the
+    queries (squeezed to [B,H,.] when ``rows`` is None, a decode step) and
+    the cache rows, ``k_pe`` and ``ckv`` of each token (at
+    ``rows = (sequence, token)`` indices, else of the one decode token)."""
+    h = L.apply_norm(cfg, _pick(norm1, j), x)
+    q_lat, q_pe, ckv, k_pe = attention.mla_latent_query(
+        cfg, _pick(attn, j), h, positions)
+    if rows is None:
+        return q_lat[:, 0], q_pe[:, 0], k_pe[:, 0], ckv[:, 0]
+    return q_lat, q_pe, k_pe[rows], ckv[rows]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _latent_out(cfg, attn, j, x, out_lat):
+    """The residual stream after attention, from the heads' latent."""
+    if out_lat.ndim == 3:                            # decode: [B,H,r]
+        out_lat = out_lat[:, None]
+    return x + attention.mla_latent_out(cfg, _pick(attn, j), out_lat)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _dense_ffn(cfg, norm2, mlp, j, x):
+    return x + L.mlp_apply(cfg, _pick(mlp, j),
+                           L.apply_norm(cfg, _pick(norm2, j), x))
+
+
+@functools.partial(jax.jit, static_argnums=0, donate_argnums=5)
+def expert_layer(cfg, norm2, experts, j, x, routed, real):
+    """One MoE layer of a chip that holds a share of the experts, as one
+    program: norm, routing over all experts, the held experts and the
+    shared expert (``moe.held_moe_apply``), residual. ``routed`` [2] adds
+    the token-expert choices on held and on absent experts of the tokens
+    that ``real`` [B*S] marks."""
+    b, s, d = x.shape
+    h = L.apply_norm(cfg, _pick(norm2, j), x)
+    out, held = moe.held_moe_apply(cfg, _pick(experts, j),
+                                   h.reshape(b * s, d))
+    held = jnp.where(real, held, 0).sum()
+    absent = real.sum() * cfg.moe.top_k - held
+    return x + out.reshape(b, s, d), routed + jnp.stack([held, absent])
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _latent_head(cfg, final_norm, w, x):
+    cdt = jnp.dtype(cfg.compute_dtype)
+    return L.apply_norm(cfg, final_norm, x) @ w.astype(cdt)
+
+
 class PagedDecoder:
-    """Per-layer decode through the page pool (dense/GQA families)."""
+    """Per-layer decode through the page pool: the dense-GQA body (dense,
+    VLM families) or, for a config with ``mla``, the MLA body over the
+    latent pools with each layer group's FFN (a dense MLP, or an expert
+    layer that holds ``cfg.moe.held`` of the experts)."""
 
     def __init__(self, cfg: ModelConfig, params, pool):
-        assert cfg.family in ("dense", "vlm") and cfg.mla is None
+        assert cfg.family in ("dense", "vlm", "moe")
+        assert cfg.mla is not None or cfg.family != "moe", \
+            "the MoE layers served are MLA's"
         self.cfg = cfg
         self.params = params
         self.view = as_view(pool)        # placement + data plane surface
@@ -79,6 +163,43 @@ class PagedDecoder:
         self.impl = attention_impl(device.platform)
         gp = params["groups"][0]
         self.stacked = not isinstance(gp, list)
+        if cfg.mla is not None:
+            self._init_latent(device)
+
+    def _init_latent(self, device):
+        """Each layer's weights as the MLA body's programs take them (its
+        group's tree and its index there, or its own tree and None; and
+        its group's kind, from ``LM.make_groups``' split), the softmax
+        scale, and the routed-choice counter: counts stay on the device
+        and fold into the registry's ``repro_moe_routed_total{to}`` when it
+        is read. Each batch's embeddings are placed on the pools'
+        ``device``, so that every layer's programs see their arguments
+        placed alike: a program compiles anew for arguments placed
+        otherwise."""
+        self.device = device
+        self.where = []
+        for group, gp in zip(make_groups(self.cfg), self.params["groups"]):
+            for i in range(group.size):
+                self.where.append((gp[i], None, group.kind)
+                                  if isinstance(gp, list)
+                                  else (gp, i, group.kind))
+        self.scale = attention.mla_softmax_scale(self.cfg)
+        self.widest: dict = {}       # chunk length, or "decode" -> batch
+        self.routed = jax.device_put(jnp.zeros(2, jnp.int32), device)
+        registry = self.view.telemetry.metrics
+        family = registry.counter(
+            "repro_moe_routed_total",
+            "Token-expert routing choices, to an expert held here or not.",
+            ("to",))
+        held, absent = family.labels("held"), family.labels("absent")
+
+        def fold():
+            n = np.asarray(self.routed)
+            if n.any():
+                self.routed = jnp.zeros_like(self.routed)
+                held.inc(int(n[0]))
+                absent.inc(int(n[1]))
+        registry.on_read(fold)
 
     def _layer(self, l: int):
         gp = self.params["groups"][0]
@@ -110,6 +231,8 @@ class PagedDecoder:
         speculative verify step (DESIGN.md §7) reads the model's argmax at
         every draft position from them."""
         cfg = self.cfg
+        if cfg.mla is not None:
+            return self._latent_chunks(chunks, want_logits)
         cdt = jnp.dtype(cfg.compute_dtype)
         b = len(chunks)
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
@@ -122,36 +245,8 @@ class PagedDecoder:
                     f"prefill chunk of {t} tokens exceeds the {limit} the "
                     f"Pallas prefill kernel fits in VMEM at {cfg.name}'s "
                     "widths; lower the scheduler's prefill_token_budget")
-        toks_pad = np.zeros((b, t), np.int32)
-        pos_pad = np.zeros((b, t), np.int32)
-        starts = np.zeros(b, np.int32)
-        mp = max(-(-(start + len(toks)) // ps) for toks, _, start in chunks)
-        tables = np.zeros((b, mp), np.int32)
-        seq_i: list[int] = []      # write coordinates of real positions
-        tok_i: list[int] = []
-        pids: list[int] = []
-        slots: list[int] = []
-        for i, (toks, pages, start) in enumerate(chunks):
-            ti = len(toks)
-            toks_pad[i, :ti] = toks
-            pos_pad[i] = start + np.arange(t)
-            starts[i] = start
-            cover = -(-(start + ti) // ps)
-            tables[i, :cover] = pages[:cover]
-            seq_i.extend([i] * ti)
-            tok_i.extend(range(ti))
-            pids.extend(int(pages[p // ps]) for p in range(start, start + ti))
-            slots.extend(p % ps for p in range(start, start + ti))
-        pids, slots, seq_i, tok_i = (jnp.asarray(a) for a in
-                                     self.view.pad_rows(pids, slots, seq_i,
-                                                        tok_i))
-        tbl = jnp.asarray(tables)
-        qs = jnp.asarray(starts)
-
-        x = self.params["embed"][jnp.asarray(toks_pad)].astype(cdt)  # [B,T,d]
-        if cfg.embed_scale:
-            x = x * np.sqrt(cfg.d_model)
-        pos = jnp.asarray(pos_pad)                       # [B,T]
+        x, pos, tbl, qs, pids, slots, seq_i, tok_i = self._chunk_inputs(
+            chunks, t)
 
         for l in range(cfg.num_layers):
             p = self._layer(l)
@@ -185,9 +280,152 @@ class PagedDecoder:
              else self.params["head"])
         return x @ w.astype(cdt)                         # [B,T,V]
 
+    def _chunk_inputs(self, chunks, t: int, b: int | None = None):
+        """Host-side inputs of a fused chunk forward padded to ``t``
+        tokens: embeddings [B,T,d], positions [B,T], page tables [B,MP],
+        chunk starts [B], and the write coordinates of the real positions
+        (page, slot, sequence and token index of each). The coordinates
+        are padded to a power of two rows (``pad_rows``); with ``b`` rows,
+        to ``b * t``, and chunks past the given ones repeat the last."""
+        cfg = self.cfg
+        cdt = jnp.dtype(cfg.compute_dtype)
+        n = len(chunks)
+        b = n if b is None else b
+        ps = self.view.page_size
+        toks_pad = np.zeros((b, t), np.int32)
+        pos_pad = np.zeros((b, t), np.int32)
+        starts = np.zeros(b, np.int32)
+        mp = max(-(-(start + len(toks)) // ps) for toks, _, start in chunks)
+        tables = np.zeros((b, mp), np.int32)
+        seq_i: list[int] = []      # write coordinates of real positions
+        tok_i: list[int] = []
+        pids: list[int] = []
+        slots: list[int] = []
+        for i, (toks, pages, start) in enumerate(chunks):
+            ti = len(toks)
+            toks_pad[i, :ti] = toks
+            pos_pad[i] = start + np.arange(t)
+            starts[i] = start
+            cover = -(-(start + ti) // ps)
+            tables[i, :cover] = pages[:cover]
+            seq_i.extend([i] * ti)
+            tok_i.extend(range(ti))
+            pids.extend(int(pages[p // ps]) for p in range(start, start + ti))
+            slots.extend(p % ps for p in range(start, start + ti))
+        if b > n:
+            for a in (toks_pad, pos_pad, starts, tables):
+                a[n:] = a[n - 1]
+            rows = _repeat_last(b * t, pids, slots, seq_i, tok_i)
+        else:
+            rows = self.view.pad_rows(pids, slots, seq_i, tok_i)
+        pids, slots, seq_i, tok_i = (jnp.asarray(a) for a in rows)
+        tbl = jnp.asarray(tables)
+        qs = jnp.asarray(starts)
+
+        x = self.params["embed"][jnp.asarray(toks_pad)].astype(cdt)  # [B,T,d]
+        if cfg.embed_scale:
+            x = x * np.sqrt(cfg.d_model)
+        pos = jnp.asarray(pos_pad)                       # [B,T]
+        return x, pos, tbl, qs, pids, slots, seq_i, tok_i
+
+    # -- the MLA body -------------------------------------------------------------
+    #
+    # Its batches are padded to the widest batch the decoder has already run
+    # at the same chunk length (or of decode steps), by repeating the last
+    # row: once set-up has run the widest batch, a batch that shrinks as
+    # requests finish, or a lone prompt's chunk, compiles nothing new.
+    # Repeated rows write the bytes of the row they repeat to its slot again
+    # and are left out of the routing counts.
+
+    def _rows(self, key, n: int) -> int:
+        b = self.widest[key] = max(n, self.widest.get(key, 0))
+        return b
+
+    def _latent_chunks(self, chunks, want_logits: bool):
+        """``forward_chunks`` of the MLA body: per layer, the chunks'
+        latent rows (``k_pe`` into the k pool, normalised ``ckv`` into the
+        v pool) land first, then every chunk's queries read their
+        sequences' latent pages through the tables in jnp
+        (``paged_latent_prefill``), causal at their positions. Returns the
+        padded batch's logits with ``want_logits``."""
+        cfg = self.cfg
+        t = max(len(toks) for toks, _, _ in chunks)
+        b = self._rows(t, len(chunks))
+        x, pos, tbl, qs, pids, slots, seq_i, tok_i = self._chunk_inputs(
+            chunks, t, b)
+        x = jax.device_put(x, self.device)
+        real = np.zeros((b, t), bool)
+        for i, (toks, _, _) in enumerate(chunks):
+            real[i, :len(toks)] = True
+        real = jnp.asarray(real.reshape(-1))
+        for l, (p, j, kind) in enumerate(self.where):
+            q_lat, q_pe, k_pe, ckv = _latent_in(cfg, p["norm1"], p["attn"],
+                                                j, x, pos, (seq_i, tok_i))
+            self.view.write_rows(l, pids, slots, k_pe, ckv)
+            att = paged_ops.paged_latent_prefill(
+                q_lat, q_pe, self.view.k_pool, self.view.v_pool, tbl, qs, l,
+                scale=self.scale)
+            x = self._latent_ffn(p, j, kind, _latent_out(cfg, p["attn"], j,
+                                                         x, att), real)
+        if want_logits:
+            return self._latent_logits(x)
+        return None
+
+    def _latent_ffn(self, p, j, kind, x, real):
+        if kind == "moe":
+            x, self.routed = expert_layer(self.cfg, p["norm2"], p["moe"], j,
+                                          x, self.routed, real)
+            return x
+        return _dense_ffn(self.cfg, p["norm2"], p["mlp"], j, x)
+
+    def _latent_logits(self, x):
+        w = (self.params["embed"].T if self.cfg.tie_embeddings
+             else self.params["head"])
+        return _latent_head(self.cfg, self.params["final_norm"], w, x)
+
+    def _latent_decode_step(self, tokens, tables, lens, positions):
+        """``decode_step`` of the MLA body: per layer one projection
+        program, the latent row write, the latent decode kernel over the
+        stacked pools (the layer picked inside it), the output program and
+        the layer's FFN. Tables are widened to ``latent_table_width``.
+        Returns the padded batch's logits: rows past the given ones repeat
+        the last."""
+        cfg = self.cfg
+        ps = self.view.page_size
+        n = tokens.shape[0]
+        b = self._rows("decode", n)
+        tokens, tables, lens, positions = _repeat_last(
+            b, tokens, tables, lens, positions)
+        pages = tables[np.arange(b), positions // ps]
+        mp = tables.shape[1]
+        tables = np.pad(tables, ((0, 0), (0, latent_table_width(mp) - mp)))
+        real = jnp.asarray(np.arange(b) < n)
+        x = jax.device_put(self.params["embed"][jnp.asarray(tokens)].astype(
+            jnp.dtype(cfg.compute_dtype)), self.device)
+        pos_b = jnp.asarray(positions[:, None])
+        pages, slots, seen, tables = (jnp.asarray(a) for a in (
+            pages, positions % ps, lens + 1, tables))
+        for l, (p, j, kind) in enumerate(self.where):
+            q_lat, q_pe, k_pe, ckv = _latent_in(cfg, p["norm1"], p["attn"],
+                                                j, x, pos_b, None)
+            with span("decode.kv_write"):
+                self.view.write_rows(l, pages, slots, k_pe, ckv)
+            with span("decode.attention"):
+                att = paged_ops.paged_latent_attention(
+                    q_lat, q_pe, self.view.k_pool, self.view.v_pool, tables,
+                    seen, l, scale=self.scale, impl=self.impl)
+            x = _latent_out(cfg, p["attn"], j, x, att)
+            with (span("decode.experts") if kind == "moe"
+                  else contextlib.nullcontext()):
+                x = self._latent_ffn(p, j, kind, x, real)
+        return self._latent_logits(x)[:, 0]
+
     def decode_step(self, tokens, tables, lens, positions):
         """tokens [B,1]; tables [B,MP]; lens [B]; positions [B]."""
         with span("decode.dispatch"):
+            if self.cfg.mla is not None:
+                return self._latent_decode_step(tokens, tables, lens,
+                                                positions)
             cfg = self.cfg
             cdt = jnp.dtype(cfg.compute_dtype)
             b = tokens.shape[0]
@@ -249,6 +487,11 @@ class ServeEngine:
                  drafter=None,
                  rehome: bool | None = None,
                  rehome_budget_frac: float = 0.5):
+        if cfg.mla is not None and (drafter is not None
+                                    or not incremental_prefill):
+            raise ValueError(
+                f"{cfg.name}: the latent (MLA) path serves greedy decode "
+                "over incremental prefill only")
         self.cfg = cfg
         self.view = as_view(pool)        # the only placement surface
         self.model = LM(cfg)

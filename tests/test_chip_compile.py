@@ -4,9 +4,11 @@ Nothing runs: the TPU compiler, which is installed without a chip, compiles
 each kernel at qwen2-0.5b's published widths (14 query heads, 2 KV heads,
 head dim 64, bf16, page_size 16) for one chip of a described ``v5e:2x2``,
 and the batched prefill kernel at the engine's chunk limit for every other
-registry width the paged decoder serves. The compiler refuses what the chip
-would refuse — a slice not aligned to the tiling, more VMEM than a kernel
-may use — which interpret mode cannot show. The pool's K/V row write is
+registry width the paged decoder serves, and the latent (MLA) decode kernel,
+row write and prefill at DeepSeek-V3's widths over the benchmark cell's
+stacked latent pools. The compiler refuses what the chip would refuse — a
+slice not aligned to the tiling, more VMEM than a kernel may use — which
+interpret mode cannot show. The pool's K/V row write is
 compiled at the benchmark cell's pool, where the compiled program shows
 whether the pool is updated in place or copied in the chip's layouts.
 
@@ -15,6 +17,7 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 
+import math
 import re
 
 import jax
@@ -170,3 +173,90 @@ def test_kv_write_updates_the_pool_in_place(one_chip, rows):
     shape = re.escape(f"bf16[{CFG.num_layers},{CELL_POOL_PAGES},{PAGE_SIZE},"
                       f"{NKV},{HD}]")
     assert not re.search(shape + r"\{[^}]*\} copy", text)
+
+
+# DeepSeek-V3's latent pools at the benchmark cell: 5 layers, 65,000 pages
+# of 16 tokens; rope keys folded two tokens to a 128-lane row, latents
+# [16, 512] (placement/geometry.py)
+DS = registry.get_config("deepseek-v3-671b")
+DS_LAYERS, DS_PAGES, DS_BATCH = 5, 65000, 128
+DS_ROPE, DS_LATENT = DS.mla.qk_rope_head_dim, DS.mla.kv_lora_rank
+
+
+def _latent_pools(sharding):
+    from repro.placement.geometry import mla_latent_geometry
+    geo = mla_latent_geometry(DS, PAGE_SIZE)
+    assert geo.k_block == (PAGE_SIZE // 2, 2 * DS_ROPE)
+    assert geo.v_block == (PAGE_SIZE, DS_LATENT)
+    lead = (DS_LAYERS, DS_PAGES)
+    return (_sds(lead + geo.k_block, BF16, sharding),
+            _sds(lead + geo.v_block, BF16, sharding))
+
+
+def _no_pool_copy(text):
+    for pool in ("8,128", "16,512"):
+        shape = re.escape(f"bf16[{DS_LAYERS},{DS_PAGES},{pool}]")
+        assert not re.search(shape + r"\{[^}]*\} copy", text), pool
+
+
+def test_latent_decode_kernel_compiles_on_the_stacked_pools(one_chip):
+    """128 query heads over one 576-wide latent key per token, batch 128,
+    tables 64 pages wide: the kernel reads both stacked pools in place,
+    the layer picked by its index maps."""
+    kp, vp = _latent_pools(one_chip)
+    mp = 64
+    text = _compiled_text(paged_ops.paged_latent_attention.lower(
+        _sds((DS_BATCH, DS.num_heads, DS_LATENT), BF16, one_chip),
+        _sds((DS_BATCH, DS.num_heads, DS_ROPE), BF16, one_chip), kp, vp,
+        _sds((DS_BATCH, mp), jnp.int32, one_chip),
+        _sds((DS_BATCH,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), scale=0.1, impl="pallas"))
+    _no_pool_copy(text)
+
+
+def test_latent_write_updates_the_pools_in_place(one_chip):
+    """A decode batch's rope-key and latent rows written into the stacked
+    latent pools: both pools aliased to the outputs, neither copied."""
+    kp, vp = _latent_pools(one_chip)
+    idx = _sds((DS_BATCH,), jnp.int32, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    text = pool_mod._write_rows_in_place.lower(
+        kp, vp, scalar, idx, idx, _sds((DS_BATCH, DS_ROPE), BF16, one_chip),
+        _sds((DS_BATCH, DS_LATENT), BF16, one_chip), scalar,
+        block=pool_mod.ROW_BLOCK).compile().as_text()
+    assert "{0}: (0, {}, may-alias)" in text
+    assert "{1}: (1, {}, may-alias)" in text
+    _no_pool_copy(text)
+
+
+def test_latent_pools_hold_no_padding(one_chip):
+    """The chip keeps both latent pools in row-major tiles with nothing
+    beyond their logical bytes (a [16, 1, 64] rope block is laid out with
+    pages on the lanes instead, which a page-by-page read must copy)."""
+    kp, vp = _latent_pools(one_chip)
+    compiled = jax.jit(lambda k, v: (k, v), donate_argnums=(0, 1)).lower(
+        kp, vp).compile()
+    logical = sum(math.prod(p.shape) * 2 for p in (kp, vp))
+    assert compiled.memory_analysis().argument_size_in_bytes == logical
+    text = compiled.as_text()
+    for p in (kp, vp):
+        dims = ",".join(map(str, p.shape))
+        assert re.search(re.escape(f"bf16[{dims}]") + r"\{3,2,1,0:T", text)
+
+
+def test_latent_prefill_reads_the_stacked_pools_without_a_layer_copy(
+        one_chip):
+    """The jnp prefill over latent pages (32 prompts of 32 tokens, the
+    cell's fill) gathers its pages with the layer index: its temporaries
+    stay far under one layer's latent pool (1.06 GB), which a slice of the
+    layer before the gather would copy."""
+    kp, vp = _latent_pools(one_chip)
+    b, t = 32, 32
+    compiled = paged_ops.paged_latent_prefill.lower(
+        _sds((b, t, DS.num_heads, DS_LATENT), BF16, one_chip),
+        _sds((b, t, DS.num_heads, DS_ROPE), BF16, one_chip), kp, vp,
+        _sds((b, 3), jnp.int32, one_chip), _sds((b,), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), scale=0.1).compile()
+    layer_bytes = math.prod(vp.shape[1:]) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 2
+    _no_pool_copy(compiled.as_text())

@@ -78,8 +78,9 @@ def test_paged_geometry_matches_historical_layout():
 def test_mla_geometry_is_asymmetric_and_compressed():
     g = geometry_for(MLA, 4)
     assert g.kind == "mla_latent" and g.shareable and g.grows
-    assert g.k_block == (4, 1, MLA.mla.qk_rope_head_dim)
-    assert g.v_block == (4, 1, MLA.mla.kv_lora_rank)
+    # 2-D blocks: four rope keys of 8 share one folded row of the page
+    assert g.k_block == (1, 4 * MLA.mla.qk_rope_head_dim)
+    assert g.v_block == (4, MLA.mla.kv_lora_rank)
     assert g.k_block != g.v_block, "latent cache must be asymmetric"
     # the whole point: far below the materialized-heads footprint
     assert g.page_bytes < paged_kv_geometry(MLA, 4).page_bytes
@@ -127,7 +128,8 @@ def test_pool_materializes_asymmetric_mla_arrays():
     pool = BwapPagePool(MLA, _domains(), page_size=4)
     assert pool.geometry.kind == "mla_latent"
     assert pool.k_pool.shape != pool.v_pool.shape
-    assert pool.k_pool.shape[-1] == MLA.mla.qk_rope_head_dim
+    # the rope keys of a 4-token page fold into one row
+    assert pool.k_pool.shape[-1] == 4 * MLA.mla.qk_rope_head_dim
     assert pool.v_pool.shape[-1] == MLA.mla.kv_lora_rank
     assert pool.page_bytes == pool.geometry.page_bytes
 
