@@ -17,6 +17,7 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 
+import dataclasses
 import math
 import re
 
@@ -31,6 +32,7 @@ from repro.kernels.paged_attention import ops as paged_ops
 from repro.models.lm import LM
 from repro.placement import pool as pool_mod
 from repro.placement.pool import BwapPagePool, MemoryDomain
+from repro.serve import engine
 from repro.serve.engine import PagedDecoder, pallas_prefill_max_chunk
 
 CFG = registry.get_config("qwen2-0.5b")
@@ -173,6 +175,59 @@ def test_kv_write_updates_the_pool_in_place(one_chip, rows):
     shape = re.escape(f"bf16[{CFG.num_layers},{CELL_POOL_PAGES},{PAGE_SIZE},"
                       f"{NKV},{HD}]")
     assert not re.search(shape + r"\{[^}]*\} copy", text)
+
+
+def _stacked_layers(sharding):
+    """qwen2-0.5b's 24 layers as the benchmark cell serves them: one
+    stacked group of bf16 weights, as shapes on ``sharding``."""
+    cfg = dataclasses.replace(CFG, param_dtype="bfloat16")
+    params = jax.eval_shape(LM(cfg).init, jax.random.PRNGKey(0))
+    return cfg, jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding),
+                             params["groups"][0])
+
+
+@pytest.mark.parametrize("b, t", [(32, 1), (8, 32)],
+                         ids=["decode_batch", "prefill_step"])
+def test_gqa_layer_programs_read_the_stacked_weights_in_place(one_chip, b,
+                                                              t):
+    """The dense-GQA body's input and output programs at qwen2-0.5b widths,
+    on the stacked 24-layer tree with the layer index traced (a decode
+    batch of 32, and 8 chunks of 32 tokens): neither holds a copy of a
+    whole stacked weight, which slicing the layer out before reading it,
+    or prefetching a whole stacked weight into vector memory, would
+    make."""
+    cfg, layers = _stacked_layers(one_chip)
+    assert all(a.shape[0] == CFG.num_layers for a in jax.tree.leaves(layers))
+    j = _sds((), jnp.int32, one_chip)
+    x = _sds((b, t, cfg.d_model), BF16, one_chip)
+    pos = _sds((b, t), jnp.int32, one_chip)
+    if t == 1:
+        rows, att = None, _sds((b, NQ, HD), BF16, one_chip)
+    else:
+        idx = _sds((b * t,), jnp.int32, one_chip)
+        rows, att = (idx, idx), _sds((b, t, NQ, HD), BF16, one_chip)
+    gqa_in, gqa_out = engine.gqa_programs("tpu")
+    texts = [gqa_in.lower(cfg, layers["norm1"], layers["attn"], j, x, pos,
+                          rows).compile().as_text(),
+             gqa_out.lower(cfg, layers["attn"], j, x, att).compile().as_text()]
+    for leaf in jax.tree.leaves(layers):
+        shape = re.escape(f"bf16[{','.join(map(str, leaf.shape))}]")
+        for text in texts:
+            assert not re.search(shape + r"\{[^}]*\} copy", text), leaf.shape
+
+
+def test_layer_pools_copy_one_layer_of_each_pool(one_chip):
+    """The dense kernels' per-layer pools at the cell's pool: one program
+    returns layer ``l`` of both pools, each copied once, with no
+    temporaries (a slice then a squeeze, each its own program, copied it
+    twice)."""
+    pool = _sds((CFG.num_layers, CELL_POOL_PAGES, PAGE_SIZE, NKV, HD), BF16,
+                one_chip)
+    mem = engine._layer_pools.lower(pool, pool, _sds(
+        (), jnp.int32, one_chip)).compile().memory_analysis()
+    layer_bytes = CELL_POOL_PAGES * PAGE_SIZE * NKV * HD * 2
+    assert 2 * layer_bytes <= mem.output_size_in_bytes < 2.01 * layer_bytes
+    assert mem.temp_size_in_bytes == 0
 
 
 # DeepSeek-V3's latent pools at the benchmark cell: 5 layers, 65,000 pages

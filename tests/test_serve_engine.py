@@ -95,6 +95,109 @@ def test_engine_decode_matches_dense_decode(small_lm):
     assert paged_next == dense_next
 
 
+def _lm(num_layers):
+    cfg = dataclasses.replace(registry.get_smoke_config("qwen2-0.5b"),
+                              num_layers=num_layers, compute_dtype="float32")
+    from repro.models.lm import LM
+    return cfg, LM(cfg).init(jax.random.PRNGKey(1))
+
+
+def _full_logits(cfg, params, tokens):
+    """Float32 logits [S, V] of every position of ``tokens``, from the
+    model's own full forward."""
+    from repro.models.lm import LM
+    model = LM(cfg)
+    x = model.embed(params, {"tokens": jnp.asarray([tokens], jnp.int32)})
+    pos = jnp.arange(len(tokens), dtype=jnp.int32)[None]
+    h, _, _ = model.hidden(params, x, pos)
+    return np.asarray(model.logits(params, h)[0])
+
+
+def _rel_err(got, want) -> float:
+    return float(np.linalg.norm(np.asarray(got) - want)
+                 / np.linalg.norm(want))
+
+
+def _decoder(cfg, params, pages=64):
+    from repro.serve.engine import PagedDecoder
+    pool = BwapPagePool(cfg, [MemoryDomain("hbm_local", pages, 819.0, True)],
+                        page_size=4)
+    return PagedDecoder(cfg, params, pool)
+
+
+def _prefill_and_decode(dec, prompts, nxt):
+    """Each prompt prefilled whole in one fused call, then one decode step
+    of token ``nxt[i]`` for each; returns the prefill logits [B,T,V] and
+    the decode logits [B,V] (both of the padded batch)."""
+    ps = dec.view.page_size
+    pages = [[dec.view.pool.alloc_page() for _ in range(len(p) // ps + 1)]
+             for p in prompts]
+    pre = dec.forward_chunks([(p, pg, 0) for p, pg in zip(prompts, pages)],
+                             want_logits=True)
+    tables = np.zeros((len(prompts), max(map(len, pages))), np.int32)
+    for i, pg in enumerate(pages):
+        tables[i, :len(pg)] = pg
+    lens = np.asarray([len(p) for p in prompts], np.int32)
+    step = dec.decode_step(np.asarray(nxt, np.int32)[:, None], tables, lens,
+                           lens)
+    return np.asarray(pre), np.asarray(step)
+
+
+@pytest.mark.parametrize("num_layers", [2, 4], ids=["list", "stacked"])
+def test_dense_body_matches_the_float32_forward(num_layers):
+    """The dense-GQA body's layer programs, over weights held as a list of
+    layers (2) or stacked (4): fused prefill and decode logits match the
+    model's float32 forward, and the real rows of a batch padded to a
+    wider batch run before equal the same rows run unpadded."""
+    cfg, params = _lm(num_layers)
+    assert isinstance(params["groups"][0], list) == (num_layers < 4)
+    prompts = [[3, 17, 29, 5, 7], [9, 1, 4], [11, 2, 8, 6, 30, 12, 4]]
+    nxt = [21, 13, 40]
+    pre, step = _prefill_and_decode(_decoder(cfg, params), prompts, nxt)
+    assert pre.shape[0] == step.shape[0] == 3
+    for i, (p, t) in enumerate(zip(prompts, nxt)):
+        full = _full_logits(cfg, params, p + [t])
+        assert _rel_err(pre[i, :len(p)], full[:len(p)]) < 1e-5
+        assert _rel_err(step[i], full[len(p)]) < 1e-5
+
+    wide = _decoder(cfg, params)
+    _prefill_and_decode(wide, prompts + [[5] * 7, [1, 2]], nxt + [3, 4])
+    pre_pad, step_pad = _prefill_and_decode(wide, prompts, nxt)
+    assert pre_pad.shape[0] == step_pad.shape[0] == 5
+    np.testing.assert_array_equal(pre_pad[:3], pre)
+    np.testing.assert_array_equal(step_pad[:3], step)
+
+
+def test_dense_body_compiles_nothing_for_narrower_batches(small_lm):
+    """After a decoder has run a batch of 4, prefill batches of 3 and 1
+    chunks and decode batches of 3 and 1 compile nothing: each is padded
+    to 4 rows, and the padding is counted in
+    ``repro_decode_rows_total{row}``."""
+    cfg, params = small_lm
+    dec = _decoder(cfg, params, pages=128)
+    rng = np.random.default_rng(5)
+
+    def serve(n):
+        prompts = [rng.integers(1, cfg.vocab_size, 6).tolist()
+                   for _ in range(n)]
+        _prefill_and_decode(dec, prompts, [1] * n)
+    serve(4)
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        serve(3)
+        serve(1)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    rows = dec.view.telemetry.metrics.get("repro_decode_rows_total")
+    assert (rows.value("real"), rows.value("pad")) == (8, 4)
+
+
 def _scatter_write(pool, layer, page_ids, slots, k_rows, v_rows):
     """The K/V write as it was before it went in place: one eager scatter
     per pool, each a new pool array."""
@@ -188,7 +291,7 @@ def test_engine_spans_land_in_the_profiler_trace(small_lm, tmp_path):
         (decode,) = inside(step, "serve.decode")
         assert inside(decode, "serve.decode.dispatch")
     for dispatch in (x for x in spans if x[2] == "serve.decode.dispatch"):
-        for name in ("layer_params", "kv_write", "attention"):
+        for name in ("kv_write", "attention"):
             assert len(inside(dispatch, "serve.decode." + name)) == \
                 cfg.num_layers
 
